@@ -94,7 +94,8 @@ import numpy as np
 
 from repro.kernels.flash_decode.ops import flash_decode_op, flash_decode_paged_op
 from repro.kernels.flash_decode.ref import decode_ref
-from repro.kernels.gmm.gmm import _tile, gmm, gmm_dual_act
+from repro.kernels.gmm.gmm import gmm, gmm_dual_act
+from repro.kernels.tiling import fit_block
 from repro.kernels.gmm.ops import (
     expert_ffn_fused,
     expert_ffn_gather,
@@ -235,7 +236,7 @@ def ffn_cell_accounting(name, g, c, d, f, balanced):
     achieved_gf = n_tok * flop_per_row / 1e9
     # The kernels' actual row tile: the largest divisor of the capacity
     # <= BM (min(BM, c) agrees only when that happens to divide c).
-    bm = _tile(c, BM)
+    bm = fit_block(c, BM)
     ragged_rows = sum(math.ceil(cnt / bm) * bm for cnt in counts)
     ragged_exec_gf = ragged_rows * flop_per_row / 1e9
     row_bytes = d * np.dtype(np.float32).itemsize
@@ -410,7 +411,7 @@ def ep_chunk_cell_accounting(name, ep, spd, cap, d, f, chunk_counts, balanced):
     n_tok = int(counts.sum())
     row_bytes = d * np.dtype(np.float32).itemsize
     flop_per_row = 6 * d * f
-    bm = _tile(cap, BM)
+    bm = fit_block(cap, BM)
     # One all_to_all leg moves the full statically shaped exchange buffer:
     # EP * SPD buckets of CAP rows per device. Chunking splits it exactly
     # K ways (the per-chunk buffers are (EP, SPD/K * CAP, D)).

@@ -21,9 +21,9 @@ from repro.runtime.serve import ServeConfig, Server
 
 n_dev = len(jax.devices())
 if n_dev >= 8:
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh_compat((n_dev // 4, 4), ("data", "model"))
+    mesh = make_mesh((n_dev // 4, 4), ("data", "model"))
     ctx = ParallelCtx(mesh=mesh, capacity_factor=4.0)
     topo = MeshTopology(2, 2)
     dist = lambda a, b: topo.hops(topo.coord(a), topo.coord(b))

@@ -356,11 +356,11 @@ XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}" py
 # count must fail loudly at ServeConfig construction.
 import sys
 import numpy as np, jax, jax.numpy as jnp
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.parallel.collectives import ep_moe_shardmap, uniform_placement
 from repro.parallel.ctx import ParallelCtx
 
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ep, spd = 4, 2
 e = ep * spd
 d, f = 16, 32

@@ -10,6 +10,13 @@ flash's "block sparsity on the diagonal".
 GQA is handled in the index maps: query head h reads KV head ``h // g`` —
 no materialized KV repetition in HBM or VMEM.
 
+Layout: the wrapper folds the head axis into the lane axis (``(B, S, H,
+hd) -> (B, S, H*hd)``, a free reshape), so each block is a ``(bq, hd)``
+panel selected by a lane-block index. Mosaic requires the last two block
+dims to be multiples of (8, 128) or the full array dims; a one-head block
+over a ``(…, H, hd)`` layout puts 1 in the sublane position and is refused.
+The compiled path therefore needs ``hd % 128 == 0`` (``can_flash_attend``).
+
 VMEM per step: q (bq,hd) + k,v (bk,hd) + scores (bq,bk) + acc (bq,hd) fp32
 ≈ 0.5 MB at bq=bk=128, hd=128 — double-buffered comfortably on v5e.
 """
@@ -22,6 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import fit_block
 
 NEG_INF = -1e30
 
@@ -43,9 +52,9 @@ def _flash_kernel(
     k_start = jk * bk
 
     def compute():
-        q = q_ref[0, :, 0, :]                       # (bq, hd)
-        k = k_ref[0, :, 0, :]                       # (bk, hd)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0]                                # (bq, hd)
+        k = k_ref[0]                                # (bk, hd)
+        v = v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) / (q.shape[-1] ** 0.5)                     # (bq, bk)
@@ -76,7 +85,7 @@ def _flash_kernel(
     @pl.when(jk == nk - 1)
     def _():
         l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -93,15 +102,8 @@ def flash_attention(
     b, s, nh, hd = q.shape
     t, nkv = k.shape[1], k.shape[2]
     g = nh // nkv
-
-    def _fit(n, pref):
-        tt = min(pref, n)
-        while n % tt:
-            tt -= 1
-        return tt
-
-    bq = _fit(s, bq)
-    bk = _fit(t, bk)
+    bq = fit_block(s, bq)
+    bk = fit_block(t, bk)
     nk = t // bk
     grid = (b, nh, s // bq, nk)
     kernel = functools.partial(
@@ -113,26 +115,25 @@ def flash_attention(
         window=window,
         t_minus_s=t - s,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda bi, h, iq, jk: (bi, iq, h, 0)),
-            pl.BlockSpec(
-                (1, bk, 1, hd), lambda bi, h, iq, jk, g=g: (bi, jk, h // g, 0)
-            ),
-            pl.BlockSpec(
-                (1, bk, 1, hd), lambda bi, h, iq, jk, g=g: (bi, jk, h // g, 0)
-            ),
+            pl.BlockSpec((1, bq, hd), lambda bi, h, iq, jk: (bi, iq, h)),
+            pl.BlockSpec((1, bk, hd), lambda bi, h, iq, jk: (bi, jk, h // g)),
+            pl.BlockSpec((1, bk, hd), lambda bi, h, iq, jk: (bi, jk, h // g)),
         ],
-        out_specs=pl.BlockSpec(
-            (1, bq, 1, hd), lambda bi, h, iq, jk: (bi, iq, h, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, bq, hd), lambda bi, h, iq, jk: (bi, iq, h)),
+        out_shape=jax.ShapeDtypeStruct((b, s, nh * hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),   # running max
             pltpu.VMEM((bq, 1), jnp.float32),     # running denom
             pltpu.VMEM((bq, hd), jnp.float32),    # running numerator
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(
+        q.reshape(b, s, nh * hd),
+        k.reshape(b, t, nkv * hd),
+        v.reshape(b, t, nkv * hd),
+    )
+    return out.reshape(b, s, nh, hd)

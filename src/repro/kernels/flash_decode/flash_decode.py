@@ -8,6 +8,13 @@ panel, turning the per-block score computation into an MXU (G x hd) @
 (hd x bt) matmul instead of G vector passes. Cache-slot validity arrives
 as an int32 mask (ring buffers / partially filled caches).
 
+Layout: the wrapper folds the kv-head axis into lanes (``(B, T, K, hd) ->
+(B, T, K*hd)``, a free reshape) so each k/v block is a ``(bt, hd)`` panel
+picked by a lane-block index, and the mask rides as ``(B, 1, T)``. Mosaic
+refuses a block whose sublane dim is a lone head (or batch row) of a
+larger axis; the compiled path needs ``hd % 128 == 0`` and ``T % 128 ==
+0`` (``can_flash_decode``).
+
 ``return_partials=True`` skips the local normalization and emits the raw
 online-softmax state ``(acc, m, l)`` instead — ``acc`` is the
 *unnormalized* weighted value sum in fp32, ``m`` the running row max and
@@ -29,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import fit_block
 
 NEG_INF = -1e30
 
@@ -93,13 +102,13 @@ def _decode_kernel(q_ref, k_ref, v_ref, m_in_ref, *refs, nt: int, partials: bool
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0]                             # (G, hd)
-    k = k_ref[0, :, 0, :]                       # (bt, hd)
-    v = v_ref[0, :, 0, :]
+    k = k_ref[0]                                # (bt, hd)
+    v = v_ref[0]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) / (q.shape[-1] ** 0.5)                     # (G, bt)
-    valid = m_in_ref[0, :]                       # (bt,)
-    s = jnp.where(valid[None, :] > 0, s, NEG_INF)
+    valid = m_in_ref[0]                          # (1, bt)
+    s = jnp.where(valid > 0, s, NEG_INF)
 
     m_prev = m_ref[:, :1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -133,14 +142,7 @@ def flash_decode(
     b, nh, hd = q.shape
     t, nkv = k.shape[1], k.shape[2]
     g = nh // nkv
-
-    def _fit(n, pref):
-        tt = min(pref, n)
-        while n % tt:
-            tt -= 1
-        return tt
-
-    bt = _fit(t, bt)
+    bt = fit_block(t, bt, 128)
     nt = t // bt
     qg = q.reshape(b, nkv, g, hd)
     grid = (b, nkv, nt)
@@ -153,9 +155,9 @@ def flash_decode(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, g, hd), lambda bi, kh, jt: (bi, kh, 0, 0)),
-            pl.BlockSpec((1, bt, 1, hd), lambda bi, kh, jt: (bi, jt, kh, 0)),
-            pl.BlockSpec((1, bt, 1, hd), lambda bi, kh, jt: (bi, jt, kh, 0)),
-            pl.BlockSpec((1, bt), lambda bi, kh, jt: (bi, jt)),
+            pl.BlockSpec((1, bt, hd), lambda bi, kh, jt: (bi, jt, kh)),
+            pl.BlockSpec((1, bt, hd), lambda bi, kh, jt: (bi, jt, kh)),
+            pl.BlockSpec((1, 1, bt), lambda bi, kh, jt: (bi, 0, jt)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -165,7 +167,12 @@ def flash_decode(
             pltpu.VMEM((g, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, k, v, valid.astype(jnp.int32))
+    )(
+        qg,
+        k.reshape(b, t, nkv * hd),
+        v.reshape(b, t, nkv * hd),
+        valid.astype(jnp.int32).reshape(b, 1, t),
+    )
     return unpack_outputs(return_partials, out, b, nh, hd)
 
 

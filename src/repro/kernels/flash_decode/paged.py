@@ -25,6 +25,12 @@ table (``ceil(W / page_size)`` entries): ring validity is always a prefix
 ``lengths`` contract (softmax is permutation-invariant over the key set
 and RoPE is applied at write time, so slot order never matters).
 
+The pool keeps its ``(P, bs, K, hd)`` layout; the wrapper views it as
+``(P, bs, K*hd)`` (a free reshape) so each fetched block is one page's
+``(bs, hd)`` panel of one kv head — Mosaic's (8, 128) block rule then
+holds for ``bs % 8 == 0`` and ``hd % 128 == 0`` (``can_flash_decode_paged``),
+where a one-head block over ``(…, K, hd)`` would be refused.
+
 ``return_partials`` matches ``flash_decode``: fp32 ``(acc, m, l)`` for the
 cross-shard LSE merge instead of locally-normalized output.
 """
@@ -71,8 +77,8 @@ def _paged_kernel(
     @pl.when(live)
     def _():
         q = q_ref[0, 0]                         # (G, hd)
-        k = k_ref[0, :, 0, :]                   # (bs, hd) — one pool page
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0]                            # (bs, hd) — one pool page
+        v = v_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) / (q.shape[-1] ** 0.5)                 # (G, bs)
@@ -106,7 +112,7 @@ def flash_decode_paged(
     interpret: bool = False,
 ):
     b, nh, hd = q.shape
-    bs, nkv = pool_k.shape[1], pool_k.shape[2]
+    n_pages, bs, nkv = pool_k.shape[:3]
     nb = block_tables.shape[1]
     g = nh // nkv
     qg = q.reshape(b, nkv, g, hd)
@@ -116,7 +122,7 @@ def flash_decode_paged(
         # Dead blocks clamp to the request's last live block: repeated
         # identical indices make the pipeline skip the page fetch.
         last = jnp.maximum(ln[bi] - 1, 0) // bs
-        return (bt[bi, jnp.minimum(jb, last)], 0, kh, 0)
+        return (bt[bi, jnp.minimum(jb, last)], 0, kh)
 
     out_shape, out_specs = output_layout(
         return_partials, b, nkv, g, hd, q.dtype,
@@ -127,8 +133,8 @@ def flash_decode_paged(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, g, hd), lambda bi, kh, jb, bt, ln: (bi, kh, 0, 0)),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
-            pl.BlockSpec((1, bs, 1, hd), kv_map),
+            pl.BlockSpec((1, bs, hd), kv_map),
+            pl.BlockSpec((1, bs, hd), kv_map),
         ],
         out_specs=out_specs,
         scratch_shapes=[
@@ -146,7 +152,7 @@ def flash_decode_paged(
         block_tables.astype(jnp.int32),
         lengths.astype(jnp.int32),
         qg,
-        pool_k,
-        pool_v,
+        pool_k.reshape(n_pages, bs, nkv * hd),
+        pool_v.reshape(n_pages, bs, nkv * hd),
     )
     return unpack_outputs(return_partials, out, b, nh, hd)

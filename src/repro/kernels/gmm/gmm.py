@@ -26,12 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _tile(n: int, pref: int) -> int:
-    t = min(pref, n)
-    while n % t:
-        t -= 1
-    return t
+from repro.kernels.tiling import fit_block
 
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int):
@@ -65,7 +60,7 @@ def gmm(
     """y[g] = x[g] @ w[g]; x: (G, C, D), w: (G, D, F) -> (G, C, F)."""
     g, c, d = x.shape
     f = w.shape[-1]
-    bm, bn, bk = _tile(c, bm), _tile(f, bn), _tile(d, bk)
+    bm, bn, bk = fit_block(c, bm), fit_block(f, bn), fit_block(d, bk)
     nk = d // bk
     grid = (g, c // bm, f // bn, nk)
     return pl.pallas_call(
@@ -117,7 +112,7 @@ def gmm_dual_act(
     """h[g] = silu(x@wg) * (x@wu); fused SwiGLU front half."""
     g, c, d = x.shape
     f = wg.shape[-1]
-    bm, bn, bk = _tile(c, bm), _tile(f, bn), _tile(d, bk)
+    bm, bn, bk = fit_block(c, bm), fit_block(f, bn), fit_block(d, bk)
     nk = d // bk
     grid = (g, c // bm, f // bn, nk)
     return pl.pallas_call(

@@ -89,7 +89,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.gmm.gmm import _tile
+from repro.kernels.tiling import ROW_ALIGN, fit_block, round_up
 
 
 def _ragged_kernel(gs_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int, bm: int):
@@ -136,7 +136,7 @@ def gmm_ragged(
     f = w.shape[-1]
     gpw = groups_per_weight
     assert g == w.shape[0] * gpw, (g, w.shape, gpw)
-    bm, bn, bk = _tile(c, bm), _tile(f, bn), _tile(d, bk)
+    bm, bn, bk = fit_block(c, bm), fit_block(f, bn), fit_block(d, bk)
     nk = d // bk
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -204,7 +204,7 @@ def gmm_dual_act_ragged(
     f = wg.shape[-1]
     gpw = groups_per_weight
     assert g == wg.shape[0] * gpw, (g, wg.shape, gpw)
-    bm, bn, bk = _tile(c, bm), _tile(f, bn), _tile(d, bk)
+    bm, bn, bk = fit_block(c, bm), fit_block(f, bn), fit_block(d, bk)
     nk = d // bk
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -233,16 +233,29 @@ def gmm_dual_act_ragged(
 # ---------------------------------------------------------------------------
 
 def _pad_rows(x: jax.Array, bm: int) -> tuple[jax.Array, int]:
-    """Append ``bm`` zero rows so a tile DMA starting anywhere inside a
-    valid segment never runs off the end of the flat array (partial tiles
-    over-read up to ``bm - 1`` rows; the tail is masked in the epilogue)."""
-    return jnp.pad(x, ((0, bm), (0, 0))), x.shape[0] + bm
+    """Pad the flat rows to a ``ROW_ALIGN`` multiple plus ``bm`` zero rows,
+    so a tile DMA starting anywhere inside a valid segment never runs off
+    the end of the array (partial tiles over-read up to ``bm - 1`` rows;
+    the tail is masked in the epilogue) and the clamp bound ``r_pad - bm``
+    is itself aligned."""
+    r_pad = round_up(x.shape[0]) + bm
+    return jnp.pad(x, ((0, r_pad - x.shape[0]), (0, 0))), r_pad
+
+
+def _row_start(off_ref, gi, mi, bm, r_max):
+    """First flat row of bucket ``gi``'s row-tile ``mi``. Layout contract:
+    every ``offsets[g]`` is a multiple of ``ROW_ALIGN`` (the dispatch
+    layouts start each segment on one — ``collectives.aligned_offsets``)
+    and so are ``bm`` and ``r_max``, which lets Mosaic prove the DMA's
+    tile alignment. An unaligned offset would read the wrong rows."""
+    start = jnp.minimum(off_ref[gi] + mi * bm, r_max)
+    return pl.multiple_of(start, ROW_ALIGN)
 
 
 def _gather_dma(x_any, xbuf, sem, off_ref, gi, mi, k, slot, *, bm, bk, r_max):
     """Descriptor for the (bm, bk) row-tile DMA of bucket ``gi`` into
     double-buffer ``slot`` (start and wait happen at the call sites)."""
-    start = jnp.minimum(off_ref[gi] + mi * bm, r_max)
+    start = _row_start(off_ref, gi, mi, bm, r_max)
     return pltpu.make_async_copy(
         x_any.at[pl.ds(start, bm), pl.ds(k * bk, bk)],
         xbuf.at[slot],
@@ -351,14 +364,14 @@ def gmm_gather(
     gpw = groups_per_weight
     g = w.shape[0] * gpw
     assert offsets.shape == (g,), (offsets.shape, g)
-    bm, bn, bk = _tile(capacity, bm), _tile(f, bn), _tile(d, bk)
+    bm, bn, bk = fit_block(capacity, bm), fit_block(f, bn), fit_block(d, bk)
     x, r_pad = _pad_rows(x, bm)
     nk = d // bk
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(g, capacity // bm, f // bn, nk),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, bk, bn), lambda gi, i, j, k, off, gs: (gi // gpw, k, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn), lambda gi, i, j, k, off, gs: (gi, i, j)),
@@ -447,14 +460,14 @@ def gmm_dual_act_gather(
     gpw = groups_per_weight
     g = wg.shape[0] * gpw
     assert offsets.shape == (g,), (offsets.shape, g)
-    bm, bn, bk = _tile(capacity, bm), _tile(f, bn), _tile(d, bk)
+    bm, bn, bk = fit_block(capacity, bm), fit_block(f, bn), fit_block(d, bk)
     x, r_pad = _pad_rows(x, bm)
     nk = d // bk
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(g, capacity // bm, f // bn, nk),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, bk, bn), lambda gi, i, j, k, off, gs: (gi // gpw, k, j)),
             pl.BlockSpec((1, bk, bn), lambda gi, i, j, k, off, gs: (gi // gpw, k, j)),
         ],
@@ -487,7 +500,7 @@ def _scatter_store(o_any, obuf, sem, off_ref, gi, mi, j, *, bm, bn, r_max):
     tile ``mi`` / column block ``j`` into the flat output (start and wait
     happen at the call sites; the clamp only guards bogus offsets — live
     tiles of a well-formed layout never hit it)."""
-    start = jnp.minimum(off_ref[gi] + mi * bm, r_max)
+    start = _row_start(off_ref, gi, mi, bm, r_max)
     return pltpu.make_async_copy(
         obuf,
         o_any.at[pl.ds(start, bm), pl.ds(j * bn, bn)],
@@ -583,10 +596,12 @@ def gmm_scatter(
     gpw = groups_per_weight
     assert g == w.shape[0] * gpw, (g, w.shape, gpw)
     assert offsets.shape == (g,), (offsets.shape, g)
-    bm, bn, bk = _tile(c, bm), _tile(f, bn), _tile(d, bk)
+    bm, bn, bk = fit_block(c, bm), fit_block(f, bn), fit_block(d, bk)
     nk = d // bk
     nmi, nj = c // bm, f // bn
-    out_pad = out_rows + bm  # a partial tile's spill never runs off the end
+    # A partial tile's spill never runs off the end; the clamp bound
+    # out_pad - bm stays ROW_ALIGN-aligned.
+    out_pad = round_up(out_rows) + bm
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(g, nmi, nj, nk),
@@ -594,7 +609,7 @@ def gmm_scatter(
             pl.BlockSpec((1, bm, bk), lambda gi, i, j, k, off, gs: (gi, i, k)),
             pl.BlockSpec((1, bk, bn), lambda gi, i, j, k, off, gs: (gi // gpw, k, j)),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, bn), x.dtype),
@@ -761,21 +776,23 @@ def gmm_fused_ffn(
     assert offsets.shape == (g,), (offsets.shape, g)
     assert wd.shape[-2] == f, (wd.shape, f)
     out_rows = r if out_rows is None else out_rows
-    bm, bf, bk = _tile(capacity, bm), _tile(f, bn), _tile(d, bk)
+    bm, bf, bk = fit_block(capacity, bm), fit_block(f, bn), fit_block(d, bk)
     x, r_pad = _pad_rows(x, bm)
     nk = d // bk
     nmi, nj = capacity // bm, f // bf
-    out_pad = out_rows + bm  # a partial tile's spill never runs off the end
+    # A partial tile's spill never runs off the end; the clamp bound
+    # out_pad - bm stays ROW_ALIGN-aligned.
+    out_pad = round_up(out_rows) + bm
     spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(g, nmi, nj, nk),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec((1, bk, bf), lambda gi, i, j, k, off, gs: (gi // gpw, k, j)),
             pl.BlockSpec((1, bk, bf), lambda gi, i, j, k, off, gs: (gi // gpw, k, j)),
             pl.BlockSpec((1, bf, dn), lambda gi, i, j, k, off, gs: (gi // gpw, j, 0)),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
             pltpu.VMEM((bm, bf), jnp.float32),   # gate accumulator
             pltpu.VMEM((bm, bf), jnp.float32),   # up accumulator

@@ -234,10 +234,12 @@ _ffn_compact_kernel.defvjp(_ffn_compact_fwd, _ffn_compact_bwd)
 
 # VMEM bound for the fully-fused kernel: it holds a (bm, d) fp32 output
 # accumulator + a (bm, d) staging tile + a double-buffered (bf, d) w_down
-# panel per step. At bm = bf = 128 and d = 4096 that is ~8.5 MB — near the
-# ~16 MB budget — so larger model dims fall back to the two-kernel pair
-# (which blocks the down-projection's output columns).
-FUSED_FFN_MAX_DOWN_DIM = 4096
+# panel per step, all linear in d. Set from the TPU compiler: at bm = bf =
+# 128 in bf16, a described v5e accepts d = 12288 and runs out of VMEM at
+# d = 16384 (tests/test_tpu_compile.py keeps d = 4096 and 6144 compiling).
+# Wider model dims fall back to the two-kernel gather+scatter pair (which
+# blocks the down-projection's output columns).
+FUSED_FFN_MAX_DOWN_DIM = 12288
 
 
 def can_gmm_fused(

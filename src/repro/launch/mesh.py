@@ -18,26 +18,18 @@ import jax
 import numpy as np
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """``axis_types=(Auto, ...)`` on jax versions that have it, {} otherwise
-    (jax <= 0.4.x meshes are implicitly all-Auto)."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where supported; the
-    portable spelling for every mesh this repo builds (launchers + tests)."""
-    try:
-        return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
-    except TypeError:  # old make_mesh without axis_types
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with explicit Auto axis types: the one spelling for
+    every mesh this repo builds (launchers + tests)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_er_mesh(*, multi_pod: bool = False, mapping: str = "er"):
@@ -67,12 +59,11 @@ def make_er_mesh(*, multi_pod: bool = False, mapping: str = "er"):
         pods.append(pod_devs[order])
     arr = np.stack(pods) if multi_pod else pods[0]
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    try:
-        return jax.sharding.Mesh(arr, axes, **_axis_type_kwargs(len(axes)))
-    except TypeError:  # old Mesh without axis_types
-        return jax.sharding.Mesh(arr, axes)
+    return jax.sharding.Mesh(
+        arr, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_test_mesh(data: int = 2, model: int = 4):
     """Small mesh for CPU tests (requires forced host device count)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -12,11 +12,13 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, smoke as smoke_cfg
 from repro.kernels.registry import parse_use_kernels
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.core.topology import MeshTopology
 from repro.models import transformer as T
 from repro.parallel.ctx import ParallelCtx
@@ -25,6 +27,7 @@ from repro.runtime.serve import ServeConfig, Server
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -66,7 +69,7 @@ def main():
     n_dev = len(jax.devices())
     if n_dev > 1:
         m = max(d for d in (2, 4, 8, 16) if n_dev % d == 0 and d <= n_dev)
-        mesh = make_mesh_compat((n_dev // m, m), ("data", "model"))
+        mesh = make_mesh((n_dev // m, m), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, capacity_factor=4.0, use_kernels=uk)
         # ER-Mapping hop distance on a model-axis ring mesh (for Algorithm 1).
         rows = int(np.sqrt(m)) if int(np.sqrt(m)) ** 2 == m else 1
@@ -77,7 +80,9 @@ def main():
         ctx = ParallelCtx(use_kernels=uk)
         dist = None
 
-    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    # bf16 residency, as served: fp32 experts do not fit a chip's HBM at
+    # published widths (one DBRX layer's 16 experts alone are 12.7 GB).
+    params = T.init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.bfloat16)
     scfg = ServeConfig(
         max_seq=args.max_seq,
         batch=args.requests,

@@ -24,7 +24,8 @@ import jax
 
 from repro.configs import get_config, smoke as smoke_cfg
 from repro.kernels.registry import parse_use_kernels
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.parallel.ctx import ParallelCtx
 from repro.parallel.sharding import state_specs, to_shardings
 from repro.runtime.checkpoint import CheckpointManager
@@ -35,6 +36,7 @@ from repro.runtime.train import init_state, make_train_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -62,13 +64,13 @@ def main():
     n_dev = len(jax.devices())
     if args.mesh != "auto":
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = make_mesh_compat((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, use_kernels=uk)
     elif n_dev > 1:
         m = 1
         while n_dev % (m * 2) == 0 and m * 2 <= 8:
             m *= 2
-        mesh = make_mesh_compat((n_dev // m, m), ("data", "model"))
+        mesh = make_mesh((n_dev // m, m), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, use_kernels=uk)
     else:
         mesh = None

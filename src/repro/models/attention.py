@@ -17,13 +17,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.kernels import registry
 from repro.models.layers import apply_rope, normal_init
 from repro.parallel.collectives import seq_parallel_decode_attend
-from repro.parallel.compat import shard_map
 from repro.parallel.ctx import ParallelCtx
 
 NEG_INF = -1e30

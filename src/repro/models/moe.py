@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.kernels import registry
+from repro.kernels.tiling import round_up
 from repro.models.layers import normal_init
 from repro.parallel.collectives import (
     bucket_capacity,
@@ -147,7 +148,9 @@ def moe_esp(
     if (
         ctx.mesh is None
         and ctx.kernels_on
-        and registry.can_gmm_gather(cap, d, f, registry.default_interpret())
+        and registry.can_gmm_gather(
+            round_up(cap), d, f, registry.default_interpret()
+        )
     ):
         # Fused dispatch-gather path (single group, no mesh): the gather
         # GMM reads token rows straight from the flat activations via
@@ -181,7 +184,7 @@ def moe_esp(
                 p["w_down"][ws],
                 offsets[ws],
                 counts[ws],
-                capacity=cap,
+                capacity=round_up(cap),
                 enabled=True,
                 compact_out=True,
                 fused=True,

@@ -18,10 +18,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import registry
-from repro.parallel.compat import shard_map
+from repro.kernels.tiling import ROW_ALIGN, round_up
 from repro.parallel.ctx import ParallelCtx
 from repro.parallel.placement import PlacementTable
 from repro.parallel.sharding import placement_specs
@@ -50,17 +51,18 @@ def dispatch_metadata(
 
     Returns ``(row_ids, offsets, counts, slots, keep)``:
 
-    * ``row_ids`` (n*k,) — source token index per *compacted* position: the
-      flat array ``x[row_ids]`` holds bucket 0's copies, then bucket 1's, …
-      (within a bucket, earlier tokens first — the same deterministic order
-      ``bucket_dispatch`` packs). Copies past capacity and out-of-range
-      bucket ids (e.g. the decode ownership sentinel) sort to each bucket's
-      tail / past every real bucket and are simply never addressed by
-      ``offsets``/``counts``.
-    * ``offsets`` (n_buckets,) int32 — bucket g's first compacted row.
+    * ``row_ids`` (R,) — source token index per flat row: ``x[row_ids]``
+      holds bucket 0's kept copies from row ``offsets[0]``, bucket 1's from
+      ``offsets[1]``, … (within a bucket, earlier tokens first — the same
+      deterministic order ``bucket_dispatch`` packs). Every segment starts
+      on a ``ROW_ALIGN``-aligned row (``aligned_offsets``), the contract of
+      the gather/scatter kernels' DMAs; rows between segments repeat token
+      0 and are never addressed. Copies past capacity and out-of-range
+      bucket ids (e.g. the decode ownership sentinel) get no row.
+    * ``offsets`` (n_buckets,) int32 — bucket g's first row.
     * ``counts`` (n_buckets,) int32 — bucket g's *kept* copies
       (== ``kept_counts``): rows ``offsets[g] .. offsets[g]+counts[g]`` of
-      the compacted array are exactly bucket g's surviving tokens.
+      the flat array are exactly bucket g's surviving tokens.
     * ``slots`` (n, k) / ``keep`` (n, k) — identical to ``bucket_dispatch``
       (within-bucket position, capacity-survival mask) for the combine.
 
@@ -75,19 +77,29 @@ def dispatch_metadata(
     # Out-of-range ids are dropped by bincount, so valid-bucket counts and
     # offsets are sentinel-proof (sentinels sort past every real bucket).
     counts_all = jnp.bincount(flat_b, length=n_buckets)
-    offsets = jnp.concatenate(
-        [jnp.zeros(1, counts_all.dtype), jnp.cumsum(counts_all)[:-1]]
-    )
-    idx_sorted = jnp.arange(n * k) - offsets[b_sorted]
+    starts = jnp.cumsum(counts_all) - counts_all
+    idx_sorted = jnp.arange(n * k) - starts[b_sorted]
 
     # Undo the sort to index by (token, k).
     slots = jnp.zeros(n * k, dtype=jnp.int32).at[order].set(
         idx_sorted.astype(jnp.int32)
     )
     keep = (slots < capacity) & (flat_b < n_buckets)  # drop out-of-range ids too
-    row_ids = (order // k).astype(jnp.int32)          # copy j came from token j//k
     counts = jnp.minimum(counts_all, capacity).astype(jnp.int32)
-    return row_ids, offsets.astype(jnp.int32), counts, slots.reshape(n, k), keep.reshape(n, k)
+    offsets = aligned_offsets(counts)
+    # Every kept copy plus each segment's rounding, and never more than a
+    # full round_up(capacity) per bucket.
+    r = min(
+        round_up(n * k + n_buckets * (ROW_ALIGN - 1)),
+        n_buckets * round_up(capacity),
+    )
+    dst = jnp.where(keep, offsets[jnp.minimum(flat_b, n_buckets - 1)] + slots, r)
+    row_ids = (
+        jnp.zeros(r, jnp.int32)
+        .at[dst]
+        .set(jnp.arange(n * k, dtype=jnp.int32) // k, mode="drop")
+    )
+    return row_ids, offsets, counts, slots.reshape(n, k), keep.reshape(n, k)
 
 
 def bucket_dispatch(
@@ -154,6 +166,17 @@ def combine_from_rows(
     vals = jnp.where(keep[..., None], vals, jnp.zeros_like(vals))
     w = (weights * keep).astype(vals.dtype)
     return jnp.einsum("nkd,nk->nd", vals, w)
+
+
+def aligned_offsets(counts: jax.Array, axis: int = -1) -> jax.Array:
+    """Segment starts for bucket-contiguous flat rows: the exclusive cumsum
+    of ``counts`` with every count rounded up to ``ROW_ALIGN``, so each
+    bucket's first row is tile-aligned — the contract the gather/scatter
+    kernels' dynamic-offset DMAs rely on (``kernels.gmm.ragged``). A
+    segment then spans ``round_up(count)`` rows; with a per-bucket row
+    budget of ``round_up(capacity)`` the aligned layout still fits."""
+    padded = round_up(counts)
+    return jnp.cumsum(padded, axis=axis) - padded
 
 
 def scatter_counts(bucket_ids: jax.Array, n_buckets: int) -> jax.Array:
@@ -345,8 +368,12 @@ def ep_moe_shardmap(
     # output buffer is ever materialized on either leg. Padded
     # bucket_dispatch/bucket_combine remain the fallback when the kernels
     # are off or shapes don't tile for the compiled kernel.
+    # Rows per bucket in the fused exchange layout: segments start on
+    # ROW_ALIGN-aligned rows (aligned_offsets), so each bucket's budget is
+    # the capacity rounded up to the alignment. Drops still happen at cap.
+    seg = round_up(cap)
     fused = use_kernels and registry.can_gmm_gather(
-        cap, d, f, registry.default_interpret()
+        seg, d, f, registry.default_interpret()
     )
     spd = slots_per_device
     # EP dispatch pipelining (ctx.ep_chunks = K): the fused branch splits
@@ -377,24 +404,24 @@ def ep_moe_shardmap(
         padded buffer reappears on either leg."""
         n = xt.shape[0]
         _, _, kept, pos, keep = dispatch_metadata(slots, total_slots, cap)
-        # Within-segment row offset of each bucket: exclusive cumsum
-        # restarting at every (rank, chunk) boundary. kc == 1 degenerates
-        # to the whole-rank cumsum of the single-shot path.
+        # Within-segment row offset of each bucket: aligned exclusive
+        # cumsum restarting at every (rank, chunk) boundary. kc == 1
+        # degenerates to the whole-rank cumsum of the single-shot path.
         kept_ck = kept.reshape(ep, kc, spc)
-        wro = jnp.cumsum(kept_ck, axis=2) - kept_ck           # (ep, kc, spc)
+        wro = aligned_offsets(kept_ck, axis=2)                # (ep, kc, spc)
         flat_b = slots.reshape(-1)
         safe_b = jnp.minimum(flat_b, total_slots - 1)
         dest = flat_b // spd                                  # >= ep for sentinels
         chunk_of = (safe_b % spd) // spc                      # owning chunk
         posr = wro.reshape(-1)[safe_b] + pos.reshape(-1)
-        posr = jnp.where(keep.reshape(-1), posr, spc * cap)   # overflow -> drop
+        posr = jnp.where(keep.reshape(-1), posr, spc * seg)   # overflow -> drop
         src = xt[jnp.repeat(jnp.arange(n), k)]
         sends = []
         for c in range(kc):
             # Copies owned by other chunks scatter out of bounds and drop —
             # each kept copy lands in exactly one chunk's buffer.
-            posr_c = jnp.where(chunk_of == c, posr, spc * cap)
-            send = jnp.zeros((ep, spc * cap, d), dtype=xt.dtype)
+            posr_c = jnp.where(chunk_of == c, posr, spc * seg)
+            send = jnp.zeros((ep, spc * seg, d), dtype=xt.dtype)
             sends.append(send.at[dest, posr_c].set(src, mode="drop"))
         return sends, kept_ck, keep, chunk_of, dest, posr
 
@@ -430,14 +457,14 @@ def ep_moe_shardmap(
             def chunk_ffn(recv, cnt, c):
                 # recv[r'] = my chunk's spc buckets' rows from source rank
                 # r', bucket-compacted; cnt[r', s] = that segment's fill.
-                roff = jnp.cumsum(cnt, axis=1) - cnt          # (ep, spc)
+                roff = aligned_offsets(cnt, axis=1)           # (ep, spc)
                 # Group gi = s*ep + r' (weight row = gi // ep, as the
-                # padded layout) -> flat row offset r'*spc*cap + roff.
-                base = jnp.arange(ep, dtype=jnp.int32)[:, None] * (spc * cap)
+                # padded layout) -> flat row offset r'*spc*seg + roff.
+                base = jnp.arange(ep, dtype=jnp.int32)[:, None] * (spc * seg)
                 offsets_g = (roff + base).transpose(1, 0).reshape(-1)
                 counts_g = cnt.transpose(1, 0).reshape(-1)
                 # compact_out: the scatter epilogue writes the down-
-                # projection back at offsets_g, so the flat (ep*spc*cap, d)
+                # projection back at offsets_g, so the flat (ep*spc*seg, d)
                 # result IS the return exchange buffer — segment r' goes
                 # straight back to source rank r', still bucket-compacted
                 # in *my* bucket order. fused=True: one kernel when
@@ -446,20 +473,20 @@ def ep_moe_shardmap(
                 # chunk when it doesn't.
                 ws = slice(c * spc, (c + 1) * spc)
                 y = registry.expert_ffn_from_rows(
-                    recv.reshape(ep * spc * cap, d),
+                    recv.reshape(ep * spc * seg, d),
                     wg[ws],
                     wu[ws],
                     wd[ws],
                     offsets_g,
                     counts_g,
-                    capacity=cap,
+                    capacity=seg,
                     groups_per_weight=ep,
                     enabled=True,
                     compact_out=True,
                     fused=True,
                 )
                 return jax.lax.all_to_all(
-                    y.reshape(ep, spc * cap, d), axis,
+                    y.reshape(ep, spc * seg, d), axis,
                     split_axis=0, concat_axis=0, tiled=False,
                 )
 
@@ -480,15 +507,15 @@ def ep_moe_shardmap(
                 backs.append(chunk_ffn(*recv[c], c))
                 recv[c] = None                    # retire chunk c's buffer
             # ONE combine over the concatenated chunk outputs: each copy's
-            # row is its chunk's block base + dest*(spc*cap) + posr — the
+            # row is its chunk's block base + dest*(spc*seg) + posr — the
             # exact coordinates dispatch_fused scattered it to on the way
             # out. A single gather + einsum keeps the per-token k-copy
             # reduction order identical to the single-shot path (bit-
             # exactness); per-chunk partial combines would re-order it.
             back = jnp.concatenate(backs, axis=0)
-            rows = chunk_of * (ep * spc * cap) + dest * (spc * cap) + posr
+            rows = chunk_of * (ep * spc * seg) + dest * (spc * seg) + posr
             out = combine_from_rows(
-                back.reshape(kc * ep * spc * cap, d),
+                back.reshape(kc * ep * spc * seg, d),
                 rows.reshape(bl * sl, k), keep, w,
             )
         else:
@@ -589,6 +616,10 @@ def ep_moe_local(
     slots = choose_slots(eid, slot_of, n_replicas, sentinel=total_slots + 1)
     bufs, pos, keep = bucket_dispatch(xt, slots, total_slots, cap)
     counts = kept_counts(slots, keep, total_slots)
+    # Bucket rows rounded up to ROW_ALIGN so the grouped kernels' row tiles
+    # meet Mosaic's block rule (can_gmm); the extra rows are dead (past
+    # every count) and sliced off before the combine.
+    bufs = jnp.pad(bufs, ((0, 0), (0, round_up(cap) - cap), (0, 0)))
     # ep_chunks: the local path has no all_to_all to hide, but it is the
     # substrate the virtual-EP serving/chaos tests run on — chunking the
     # grouped FFN the same way keeps the chunked program on the hot path
@@ -610,7 +641,7 @@ def ep_moe_local(
         for c in range(kc)
     ]
     y = ys[0] if kc == 1 else jnp.concatenate(ys, axis=0)
-    out = bucket_combine(y, slots, pos, keep, w)
+    out = bucket_combine(y[:, :cap], slots, pos, keep, w)
     return out.reshape(b, s, d)
 
 

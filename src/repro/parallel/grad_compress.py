@@ -19,9 +19,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.parallel.compat import shard_map
 
 
 def _quant(x: jax.Array, key: jax.Array):

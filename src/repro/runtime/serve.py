@@ -42,6 +42,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.ni_balancer import (
@@ -56,6 +58,7 @@ from repro.models import transformer as T
 from repro.parallel.collectives import validate_ep_chunks
 from repro.parallel.ctx import ParallelCtx
 from repro.parallel.placement import PlacementTable
+from repro.parallel.sharding import cache_specs, chunk_specs, to_shardings
 from repro.runtime.migration_driver import MOE_WEIGHTS, MigrationDriver
 
 
@@ -209,6 +212,41 @@ class PagePool:
             self._free.append(p)
 
 
+def _paged_cache_shardings(cfg: ModelConfig, cache: dict, ctx: ParallelCtx):
+    """NamedShardings of a paged serving cache under ``ctx.mesh`` (the
+    ``sharding.cache_specs`` rule table); None off-mesh or for dense
+    caches. ``cache`` may hold arrays, shapes or tracers."""
+    if ctx.mesh is None or not A.is_paged(cache["layers"]):
+        return None
+    batch = cache["layers"]["lengths"].shape[-1]
+    return to_shardings(ctx.mesh, cache_specs(cfg, cache, ctx, batch))
+
+
+def _decode_step(params, token, cache, *, cfg, ctx, **kw):
+    """``transformer.decode_step`` with the paged cache pinned to its mesh
+    layout on the way out — the same layout ``Server.decode`` places it in
+    on the way in — so host-side table/length edits between ticks never
+    present the step with a new input sharding (one compiled program)."""
+    logits, cache, stats = T.decode_step(params, token, cache, cfg=cfg, ctx=ctx, **kw)
+    shardings = _paged_cache_shardings(cfg, cache, ctx)
+    if shardings is not None:
+        cache = jax.lax.with_sharding_constraint(cache, shardings)
+    return logits, cache, stats
+
+
+def _expand_slots(w: jax.Array, rows: np.ndarray, ctx: ParallelCtx) -> jax.Array:
+    """Logical expert rows -> physical slot rows (dim 1). Under a mesh the
+    slot dim comes out sharded over the model axis, so each device builds
+    and keeps only its own slots and the step never reshards them."""
+    if ctx.mesh is None:
+        return jnp.take(w, rows, axis=1)
+    spec = P(None, ctx.model_axis, *(None,) * (w.ndim - 2))
+    return jax.jit(
+        lambda a: jnp.take(a, rows, axis=1),
+        out_shardings=NamedSharding(ctx.mesh, spec),
+    )(w)
+
+
 class Server:
     def __init__(
         self,
@@ -270,9 +308,14 @@ class Server:
                 )
             else:
                 rows = np.arange(n_slots) % cfg.n_experts
+            # One weight at a time, each finished (and its logical array
+            # freed) before the next starts: peak HBM is the parameters
+            # plus one expanded weight, not all three in flight at once.
+            # The caller's ``params`` dict is updated in place, so it holds
+            # no second reference to the logical rows.
+            moe = self.params["layers"]["moe"]
             for w in MOE_WEIGHTS:
-                arr = self.params["layers"]["moe"][w]
-                self.params["layers"]["moe"][w] = jnp.take(arr, rows, axis=1)
+                moe[w] = jax.block_until_ready(_expand_slots(moe[w], rows, ctx))
             # The one placement substrate: expert e natively lives in slot
             # e, i.e. on device e // spd. The balancer plans against it
             # (committed + in-flight view) and the jitted decode routes by
@@ -300,7 +343,10 @@ class Server:
             self.state = None
             self.driver = None
 
-        prefill_kw: dict = {}
+        # KV caches hold activations at the parameters' dtype (bf16 serving
+        # keeps bf16 pages, so the decode kernels see one input dtype).
+        self.cache_dtype = params["embed"].dtype
+        prefill_kw: dict = {"dtype": self.cache_dtype}
         if serve_cfg.paged:
             self.page_size, self.n_blocks = A.paged_layout(
                 cfg, serve_cfg.max_seq, serve_cfg.page_size
@@ -341,7 +387,7 @@ class Server:
                         f"{self.page_size} (paged_layout shrank it from "
                         f"{serve_cfg.page_size})"
                     )
-            prefill_kw = dict(
+            prefill_kw.update(
                 paged=True,
                 page_size=serve_cfg.page_size,
                 n_pages=self.n_pool_pages,
@@ -355,7 +401,7 @@ class Server:
         # warning), but under a mesh the donation can be accepted and
         # generate() then concatenates a deleted token array.
         self._decode = jax.jit(
-            functools.partial(T.decode_step, cfg=cfg, ctx=ctx),
+            functools.partial(_decode_step, cfg=cfg, ctx=ctx),
             donate_argnums=(2,),
         )
         # Chunk operands are tiny host-built metadata; under a mesh they
@@ -363,8 +409,6 @@ class Server:
         # the fused step never re-triggers layout inference per tick.
         self._chunk_shardings = None
         if serve_cfg.paged and serve_cfg.prefill_chunk and ctx.mesh is not None:
-            from repro.parallel.sharding import chunk_specs, to_shardings
-
             self._chunk_shardings = to_shardings(ctx.mesh, chunk_specs())
         self._prefill = jax.jit(
             functools.partial(
@@ -515,6 +559,7 @@ class Server:
             self.cfg,
             b,
             self.scfg.max_seq,
+            self.cache_dtype,
             paged=True,
             page_size=self.scfg.page_size,
             n_pages=self.n_pool_pages,
@@ -765,6 +810,11 @@ class Server:
             # decode compute below.
             self.drain_migrations()
         placement = self.table.device_view() if self.use_balancer else None
+        shardings = _paged_cache_shardings(self.cfg, cache, self.ctx)
+        if shardings is not None:
+            # Host-built leaves (fresh cache, rebuilt tables) join the
+            # step's mesh layout; leaves already there are left in place.
+            cache = jax.device_put(cache, shardings)
         slot_mask = None
         if self.scfg.paged:
             # Continuous batching: released/empty rows still step (fixed

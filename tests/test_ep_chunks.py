@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config, smoke
-from repro.launch.mesh import make_mesh_compat
+from repro.launch.mesh import make_mesh
 from repro.models import moe as M
 from repro.models import transformer as T
 from repro.parallel.collectives import validate_ep_chunks
@@ -142,7 +142,7 @@ def test_no_mesh_bad_chunk_count_fails_on_every_branch():
 def test_mesh_chunked_parity_and_grads():
     cfg = _cfg()
     params = _skewed_params(cfg)
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for shape in ((2, 8), (4, 1)):   # prefill and decode shapes
         x = jax.random.normal(jax.random.PRNGKey(2), (*shape, cfg.d_model))
         base = None

@@ -60,8 +60,10 @@ from repro.kernels.gmm.ref import (
     gmm_ref,
     scatter_rows_ref,
 )
+from repro.kernels.tiling import ROW_ALIGN, round_up
 from repro.models.moe import moe_dense, moe_ep, moe_esp, moe_init
 from repro.parallel.collectives import (
+    aligned_offsets,
     bucket_capacity,
     bucket_combine,
     bucket_dispatch,
@@ -80,13 +82,14 @@ CTX_OFF = ParallelCtx(capacity_factor=8.0, use_kernels=False)
 
 
 def _segments(counts, pad_between=0):
-    """Random flat rows with bucket-contiguous segments; returns
-    (rows, offsets) with ``pad_between`` junk rows between segments."""
+    """Row count and offsets of bucket-contiguous segments, each starting
+    on a ``ROW_ALIGN``-aligned row (the gather/scatter kernels' layout
+    contract), with at least ``pad_between`` junk rows between segments."""
     counts = np.asarray(counts)
     offsets = np.zeros(len(counts), np.int32)
     pos = 0
     for i, c in enumerate(counts):
-        offsets[i] = pos
+        offsets[i] = pos = round_up(pos)
         pos += int(c) + pad_between
     return pos, jnp.asarray(offsets, jnp.int32)
 
@@ -154,9 +157,9 @@ def test_gmm_gather_segment_at_array_end():
     array — the wrapper's row padding must absorb it (regression for the
     clamped-DMA tile shift)."""
     g, cap, d, f = 2, 128, 16, 24
-    counts = [100, 129 - 100]  # second segment ends exactly at R
+    counts = [100, 133 - 104]  # second segment (aligned start 104) ends at R
     r, offsets = _segments(counts)
-    assert r == 129  # deliberately not a multiple of any tile size
+    assert r == 133  # deliberately not a multiple of any tile size
     ks = jax.random.split(RNG, 2)
     x = jax.random.normal(ks[0], (r, d))
     w = jax.random.normal(ks[1], (g, d, f)) * 0.1
@@ -290,8 +293,11 @@ def test_gmm_scatter_partial_tile_spill_is_overwritten():
     overwrite the spill with the later bucket's real rows (the
     overlap-overwrite contract)."""
     g, cap, d, f = 3, 16, 8, 12
-    counts = [5, 3, 7]  # contiguous, none a multiple of the 16-row tile
+    counts = [5, 3, 7]  # none a multiple of the 16-row tile
     r, offsets = _segments(counts)
+    # Aligned segments start 8 rows apart, inside the previous bucket's
+    # 16-row tile: every later bucket's rows receive an earlier spill.
+    assert (np.diff(np.asarray(offsets)) < cap).all()
     ks = jax.random.split(RNG, 2)
     x = jax.random.normal(ks[0], (g, cap, d))
     w = jax.random.normal(ks[1], (g, d, f)) * 0.1
@@ -299,8 +305,7 @@ def test_gmm_scatter_partial_tile_spill_is_overwritten():
     out = np.asarray(gmm_scatter_op(x, w, offsets, gs, out_rows=r))
     ref = np.asarray(scatter_rows_ref(gmm_ragged_ref(x, w, gs), offsets, gs, r))
     live = _live_rows(counts, offsets, r)
-    assert live.all()  # contiguous segments tile the array fully
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(out[live], ref[live], rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("gpw", [2, 3])
@@ -530,7 +535,7 @@ def test_fused_vmem_gate_falls_back_to_pair(monkeypatch):
     """Shapes past the fused kernel's VMEM bound (large model dim) must
     fall back to the gather+scatter pair — same results, no error. The
     bound is shrunk so the test doesn't need a genuinely huge tensor."""
-    assert not registry.can_gmm_fused(16, 8192, 128, True)
+    assert not registry.can_gmm_fused(16, 16384, 128, True)
     g, cap, d, f = 3, 16, 8, 12
     counts = [4, 16, 0]
     r, offsets = _segments(counts)
@@ -695,8 +700,12 @@ def test_dispatch_metadata_compacted_order_is_deterministic():
     ids = jnp.asarray([[1], [0], [1], [0], [1]], jnp.int32)
     row_ids, offsets, counts, _, _ = dispatch_metadata(ids, 2, 8)
     np.testing.assert_array_equal(np.asarray(counts), [2, 3])
-    np.testing.assert_array_equal(np.asarray(offsets), [0, 2])
-    np.testing.assert_array_equal(np.asarray(row_ids), [1, 3, 0, 2, 4])
+    # Segments start on ROW_ALIGN-aligned rows (the gather/scatter DMA
+    # contract): bucket 0 at row 0, bucket 1 at row 8.
+    np.testing.assert_array_equal(np.asarray(offsets), [0, ROW_ALIGN])
+    row_ids = np.asarray(row_ids)
+    np.testing.assert_array_equal(row_ids[0:2], [1, 3])
+    np.testing.assert_array_equal(row_ids[ROW_ALIGN : ROW_ALIGN + 3], [0, 2, 4])
 
 
 # ---------------------------------------------------------------------------
@@ -815,12 +824,12 @@ def test_tiled_placement_grows_replica_table():
 def test_moe_ep_rejects_underprovisioned_slots():
     """Fewer physical slots than weight rows would silently drop experts —
     moe_ep must refuse with a clear error, not truncate."""
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     cfg = dataclasses.replace(
         smoke(get_config("dbrx-132b")), n_experts=3, experts_per_token=2
     )
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rng = jax.random.PRNGKey(0)
     p = moe_init(rng, cfg)
     x = jax.random.normal(rng, (2, 8, cfg.d_model)) * 0.5
@@ -833,12 +842,12 @@ def test_moe_ep_non_divisible_rows_single_device(monkeypatch):
     """moe_ep with n_rows % ep != 0 on a 1-device mesh: force the tiled
     branch by passing slots_per_device explicitly, then check parity with
     the dense oracle (tokens must land on slots holding their expert)."""
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     cfg = dataclasses.replace(
         smoke(get_config("dbrx-132b")), n_experts=3, experts_per_token=2
     )
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rng = jax.random.PRNGKey(0)
     p = moe_init(rng, cfg)
     x = jax.random.normal(rng, (2, 8, cfg.d_model)) * 0.5
@@ -883,10 +892,10 @@ def test_moe_esp_fused_parity(moe_cfg, shape):
 def test_moe_ep_fused_parity(moe_cfg, shape):
     """EP dispatch on a 1x1 mesh with kernels on takes the fused
     rank-compacted all_to_all path (interpret mode on CPU)."""
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
     b, s = shape
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rng = jax.random.PRNGKey(0)
     p = moe_init(rng, moe_cfg)
     x = jax.random.normal(rng, (b, s, moe_cfg.d_model)) * 0.5
@@ -910,9 +919,9 @@ def test_moe_ep_fused_compact_grad(moe_cfg):
     """Gradients through the full fused EP path — compact scatter epilogue
     (custom_vjp), return all_to_all, and metadata combine — must match the
     dense oracle."""
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rng = jax.random.PRNGKey(0)
     p = moe_init(rng, moe_cfg)
     x = jax.random.normal(rng, (2, 8, moe_cfg.d_model)) * 0.5
@@ -926,3 +935,59 @@ def test_moe_ep_fused_compact_grad(moe_cfg):
         np.testing.assert_allclose(
             np.asarray(gd[key]), np.asarray(ge[key]), rtol=1e-4, atol=1e-5
         )
+
+
+# ---------------------------------------------------------------------------
+# row-alignment contract of the dispatch layouts
+# ---------------------------------------------------------------------------
+
+def test_aligned_offsets_round_each_segment():
+    counts = jnp.asarray([[5, 0, 8, 9], [1, 16, 3, 0]], jnp.int32)
+    off = np.asarray(aligned_offsets(counts, axis=1))
+    np.testing.assert_array_equal(off, [[0, 8, 8, 16], [0, 8, 24, 32]])
+
+
+@pytest.mark.parametrize("path", ["esp_no_mesh", "ep_mesh_1x1"])
+def test_dispatch_layouts_give_aligned_offsets(path, monkeypatch):
+    """Both producers of flat-row offsets start every bucket segment on a
+    ROW_ALIGN-aligned row — the promise the gather/scatter kernels'
+    ``pl.multiple_of`` hints make to the TPU compiler — at skewed routing
+    where the raw counts are not multiples of 8, and still match the dense
+    oracle."""
+    from repro.launch.mesh import make_mesh
+
+    cfg = dataclasses.replace(
+        smoke(get_config("dbrx-132b")), n_experts=4, experts_per_token=2
+    )
+    rng = jax.random.PRNGKey(3)
+    p = moe_init(rng, cfg)
+    p["router"] = p["router"].at[:, 0].multiply(6.0)   # expert 0 runs hot
+    x = jax.random.normal(rng, (3, 7, cfg.d_model)) * 0.5
+    dense, _ = moe_dense(p, x, cfg, CTX_OFF)
+
+    seen = []
+    orig = registry.expert_ffn_from_rows
+
+    def spy(rows, wg, wu, wd, offsets, group_sizes, **kw):
+        jax.debug.callback(
+            lambda o, c: seen.append((np.asarray(o), np.asarray(c))),
+            offsets, group_sizes,
+        )
+        return orig(rows, wg, wu, wd, offsets, group_sizes, **kw)
+
+    monkeypatch.setattr(registry, "expert_ffn_from_rows", spy)
+    if path == "esp_no_mesh":
+        out, _ = moe_esp(p, x, cfg, CTX_ON)
+    else:
+        mesh = make_mesh((1, 1), ("data", "model"))
+        ctx = ParallelCtx(mesh=mesh, capacity_factor=8.0, use_kernels=True)
+        with mesh:
+            out, _ = jax.jit(lambda p_, x_: moe_ep(p_, x_, cfg, ctx))(p, x)
+    jax.block_until_ready(out)
+    assert seen, "the flat-row FFN never ran"
+    assert any((c % ROW_ALIGN).any() for _, c in seen)   # raw counts ragged
+    for off, _ in seen:
+        assert (off % ROW_ALIGN == 0).all(), off
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(dense), rtol=1e-5, atol=1e-5
+    )

@@ -35,8 +35,8 @@ def test_ep_esp_decode_parity_8dev():
         from repro.configs import get_config, smoke
         from repro.models.moe import moe_dense, moe_ep, moe_esp, moe_init
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, capacity_factor=8.0)
         cfg = dataclasses.replace(smoke(get_config("dbrx-132b")),
                                   n_experts=4, experts_per_token=2)
@@ -75,8 +75,8 @@ def test_ep_fused_dispatch_parity_8dev():
         from repro.configs import get_config, smoke
         from repro.models.moe import moe_dense, moe_ep, moe_init
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, capacity_factor=8.0, use_kernels=True)
         ref_ctx = ParallelCtx(capacity_factor=8.0, use_kernels=False)
         rng = jax.random.PRNGKey(0)
@@ -114,8 +114,8 @@ def test_ep_fused_ffn_single_kernel_8dev():
         from repro.kernels import registry
         from repro.models.moe import moe_dense, moe_ep, moe_init
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, capacity_factor=8.0, use_kernels=True)
         ref_ctx = ParallelCtx(capacity_factor=8.0, use_kernels=False)
         cfg = dataclasses.replace(smoke(get_config("dbrx-132b")),
@@ -164,8 +164,8 @@ def test_ep_compact_combine_skewed_and_validation_8dev():
         import jax, jax.numpy as jnp
         from repro.parallel.collectives import ep_moe_shardmap, uniform_placement
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         ep = 4
         e, d, f, k = 8, 8, 16, 2
         b, s = 4, 8
@@ -239,8 +239,8 @@ def test_ep_chunked_dispatch_parity_8dev():
         import jax, jax.numpy as jnp
         from repro.parallel.collectives import ep_moe_shardmap, uniform_placement
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         ep = 4
         e, d, f, k = 8, 8, 16, 2    # spd = 2 -> ep_chunks in {1, 2}
         rng = jax.random.PRNGKey(0)
@@ -305,8 +305,8 @@ def test_gqa_kv_replicated_flash_attention_8dev():
         from repro.configs import get_config, smoke
         from repro.models import attention as A
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(smoke(get_config("llama3.2-1b")),
                                   n_heads=8, n_kv_heads=2)
         p = A.attn_init(jax.random.PRNGKey(0), cfg)
@@ -342,8 +342,8 @@ def test_gqa_kv_replicated_flash_decode_8dev():
         from repro.configs import get_config, smoke
         from repro.models import attention as A
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(smoke(get_config("llama3.2-1b")),
                                   n_heads=8, n_kv_heads=2)
         ctx_on = ParallelCtx(mesh=mesh, use_kernels=True, seq_parallel_kv=False)
@@ -389,8 +389,8 @@ def test_ep_gradient_parity_8dev():
         from repro.configs import get_config, smoke
         from repro.models.moe import moe_dense, moe_ep, moe_init
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(smoke(get_config("dbrx-132b")),
                                   n_experts=4, experts_per_token=2)
         rng = jax.random.PRNGKey(0)
@@ -421,8 +421,8 @@ def test_seq_parallel_decode_and_compressed_sync_8dev():
             seq_parallel_decode_attend, seq_parallel_decode_kernel_eligible)
         from repro.models.attention import gqa_attend
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh)
         q = jax.random.normal(jax.random.PRNGKey(1), (2, 1, 8, 16))
         k = jax.random.normal(jax.random.PRNGKey(2), (2, 16, 4, 16))
@@ -441,7 +441,7 @@ def test_seq_parallel_decode_and_compressed_sync_8dev():
             out_k = jax.jit(lambda q,k,v,m: seq_parallel_decode_attend(q,k,v,m,ctx_k))(q,k,v,mask)
         assert float(jnp.max(jnp.abs(out_k - ref))) < 1e-5, "kernelized merge parity"
         # compressed cross-pod sync: mean preserved within int8 error
-        mesh2 = make_mesh_compat((2, 2, 2), ("pod", "data", "model"))
+        mesh2 = make_mesh((2, 2, 2), ("pod", "data", "model"))
         from repro.parallel.grad_compress import compressed_pod_mean
         tree = {"w": jax.random.normal(jax.random.PRNGKey(5), (64, 33))}
         with mesh2:
@@ -465,8 +465,8 @@ def test_paged_decode_under_mesh_8dev():
         from repro.configs import get_config, smoke
         from repro.models import attention as A
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(smoke(get_config("llama3.2-1b")),
                                   n_heads=8, n_kv_heads=4)
         ctx0 = ParallelCtx()
@@ -508,8 +508,8 @@ def test_server_migration_preserves_outputs_8dev():
         from repro.models import transformer as T
         from repro.runtime.serve import Server, ServeConfig
         from repro.parallel.ctx import ParallelCtx
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = ParallelCtx(mesh=mesh, capacity_factor=8.0)
         cfg = dataclasses.replace(smoke(get_config("dbrx-132b")),
                                   n_experts=8, experts_per_token=2)
@@ -541,8 +541,8 @@ def test_dryrun_machinery_small_mesh():
         import repro.launch.dryrun as D
         from repro.configs import get_config
         from repro.configs.base import ShapeConfig
-        from repro.launch.mesh import make_mesh_compat
-        mesh = make_mesh_compat((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = dataclasses.replace(get_config("llama3.2-1b"), n_layers=2)
         shape = ShapeConfig("t", 256, 8, "train")
         with mesh:

@@ -383,7 +383,7 @@ def test_restore_elastic_onto_different_mesh_shape(tmp_path):
     """Checkpoints written with no mesh restore onto a fresh (1, 1) mesh:
     arrays come back bitwise equal and placed under the new shardings."""
     from jax.sharding import NamedSharding, PartitionSpec
-    from repro.launch.mesh import make_mesh_compat
+    from repro.launch.mesh import make_mesh
     from repro.runtime.checkpoint import CheckpointManager
 
     state = {"w": np.arange(12, dtype=np.float32).reshape(3, 4),
@@ -391,7 +391,7 @@ def test_restore_elastic_onto_different_mesh_shape(tmp_path):
     mgr = CheckpointManager(str(tmp_path), keep=2)
     mgr.save(7, state, extra={"data_step": 7})
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def sharding_fn(mesh, template):
         return jax.tree.map(

@@ -232,7 +232,7 @@ def test_nan_fault_fails_only_affected_request():
 # ---------------------------------------------------------------------------
 
 def _chaos_run(seed, n_requests=4, max_new=7, skew_router=False,
-               prefill_chunk=None, ep_chunks=1):
+               prefill_chunk=None, ep_chunks=1, pool_pages=10):
     cfg = _moe_cfg()
     params = T.init_params(RNG, cfg)
     if skew_router:
@@ -254,7 +254,7 @@ def _chaos_run(seed, n_requests=4, max_new=7, skew_router=False,
     cut = int(np.argmax(ref[0] == eos)) + 1
     expected[0] = ref[0][:cut]
 
-    srv = _server(cfg, params, batch=3, pool_pages=10, alpha=0.1,
+    srv = _server(cfg, params, batch=3, pool_pages=pool_pages, alpha=0.1,
                   prefill_chunk=prefill_chunk, ep_chunks=ep_chunks, **moe_kw)
     # poison slot 0: admission always picks the lowest free slot, so slot 0
     # is the one guaranteed to hold a live request mid-run
@@ -274,13 +274,25 @@ def _chaos_run(seed, n_requests=4, max_new=7, skew_router=False,
     return sched
 
 
+def _pool_preemptions(sched) -> int:
+    return sum(
+        1 for _, kind, d in sched.events
+        if kind == "preempt" and d[1] == "pool-exhausted"
+    )
+
+
 def test_chaos_parity_moe():
     """Ragged arrivals + undersized pool + device death + straggler + NaN
     step + mid-stream EOS: every admitted request completes and every
     output is bit-identical to the sequential fault-free decode — including
-    requests that were preempted and recomputed. No decode step raises."""
-    sched = _chaos_run(seed=14)
-    assert sched.n_preempted > 0     # the chaos actually bit
+    requests that were preempted and recomputed. No decode step raises.
+
+    The pool is sized so the chaos plan's pressure window (5 of 7 pages
+    held) leaves less than the live requests' page growth: a pool-exhausted
+    preemption is forced by page arithmetic, whatever tokens the model
+    happens to emit."""
+    sched = _chaos_run(seed=14, pool_pages=7)
+    assert _pool_preemptions(sched) > 0     # the chaos actually bit
 
 
 def test_chaos_parity_with_concurrent_migration_stream():
@@ -301,8 +313,8 @@ def test_chaos_parity_chunked_dispatch():
     pool pressure, NaN faults, preemption and recompute: every stream must
     stay bit-identical to the *unchunked* sequential fault-free oracle,
     because chunking is a schedule, not a numerical change."""
-    sched = _chaos_run(seed=14, ep_chunks=3)
-    assert sched.n_preempted > 0
+    sched = _chaos_run(seed=14, ep_chunks=3, pool_pages=7)
+    assert _pool_preemptions(sched) > 0
     assert sched.server.scfg.ep_chunks == 3
     assert sched.stats()["ep_chunks"] == 3   # ops visibility
 

@@ -55,18 +55,20 @@ def moe_init(key, cfg: ModelConfig, dtype=jnp.float32) -> dict:
 def route(
     p: dict, x: jax.Array, cfg: ModelConfig
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Top-k routing. Returns (expert_ids, weights, aux_loss)."""
-    logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, ids = jax.lax.top_k(probs, cfg.experts_per_token)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
-    # Switch-style aux loss: E * sum_e fraction_tokens_e * mean_prob_e.
-    e = cfg.n_experts
-    one_hot = jax.nn.one_hot(ids, e, dtype=jnp.float32)     # (..., k, E)
-    frac = jnp.mean(jnp.sum(one_hot, axis=-2).reshape(-1, e), axis=0)
-    mean_prob = jnp.mean(probs.reshape(-1, e), axis=0)
-    aux = e * jnp.sum(frac * mean_prob)
-    return ids, weights.astype(x.dtype), aux
+    """Top-k routing (named scope ``router``). Returns (expert_ids,
+    weights, aux_loss)."""
+    with jax.named_scope("router"):
+        logits = jnp.einsum("...d,de->...e", x.astype(jnp.float32), p["router"])
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, ids = jax.lax.top_k(probs, cfg.experts_per_token)
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        # Switch-style aux loss: E * sum_e fraction_tokens_e * mean_prob_e.
+        e = cfg.n_experts
+        one_hot = jax.nn.one_hot(ids, e, dtype=jnp.float32)     # (..., k, E)
+        frac = jnp.mean(jnp.sum(one_hot, axis=-2).reshape(-1, e), axis=0)
+        mean_prob = jnp.mean(probs.reshape(-1, e), axis=0)
+        aux = e * jnp.sum(frac * mean_prob)
+        return ids, weights.astype(x.dtype), aux
 
 
 def zero_aux(cfg: ModelConfig) -> dict:

@@ -413,8 +413,13 @@ def decode_step(
     aux = zero_aux(cfg)
     chunk_logits = None
     if pat == "attn":
+        # Named scopes (op metadata only; the compiled program is the
+        # same): ``decode/`` holds the decode rows' attention and MoE,
+        # ``lane/`` the prefill lane's chunk through every layer and its
+        # LM head, ``lm_head`` the decode rows' logits.
         if chunk is not None:
-            xc = _embed(params, chunk["tokens"], cfg, ctx)       # (1, C, d)
+            with jax.named_scope("lane"):
+                xc = _embed(params, chunk["tokens"], cfg, ctx)   # (1, C, d)
             n_chunk = chunk["tokens"].shape[1]
             cvalid = (jnp.arange(n_chunk) < chunk["length"])[None, :]
 
@@ -424,37 +429,39 @@ def decode_step(
             else:
                 h, hc, a_sum = carry
             p_l, c_l = inp
-            z = rms_norm(h, p_l["ln1"], cfg.norm_eps)
-            o, c_new = decode_attention(p_l["attn"], z, c_l, pos, cfg, ctx)
-            h = h + o
-            z2 = rms_norm(h, p_l["ln2"], cfg.norm_eps)
-            if cfg.is_moe:
-                y, a = moe_apply(
-                    p_l["moe"], z2, cfg, ctx, placement=placement,
-                    token_mask=None if slot_mask is None else slot_mask[:, None],
-                )
-            else:
-                y, a = mlp_apply(p_l["mlp"], z2, ctx), zero_aux(cfg)
-            h = h + y
+            with jax.named_scope("decode"):
+                z = rms_norm(h, p_l["ln1"], cfg.norm_eps)
+                o, c_new = decode_attention(p_l["attn"], z, c_l, pos, cfg, ctx)
+                h = h + o
+                z2 = rms_norm(h, p_l["ln2"], cfg.norm_eps)
+                if cfg.is_moe:
+                    y, a = moe_apply(
+                        p_l["moe"], z2, cfg, ctx, placement=placement,
+                        token_mask=None if slot_mask is None else slot_mask[:, None],
+                    )
+                else:
+                    y, a = mlp_apply(p_l["mlp"], z2, ctx), zero_aux(cfg)
+                h = h + y
             if chunk is None:
                 return (h, jax.tree.map(jnp.add, a_sum, a)), c_new
             # Prefill lane: the chunk flows through the same layer against
             # the pool the decode lane just wrote (disjoint pages).
-            zc = rms_norm(hc, p_l["ln1"], cfg.norm_eps)
-            oc, c_new = chunk_prefill_attention(
-                p_l["attn"], zc, c_new, chunk["table"],
-                chunk["start"], chunk["length"], cfg, ctx,
-            )
-            hc = hc + oc
-            z2c = rms_norm(hc, p_l["ln2"], cfg.norm_eps)
-            if cfg.is_moe:
-                yc, ac = moe_apply(
-                    p_l["moe"], z2c, cfg, ctx, placement=placement,
-                    token_mask=cvalid,
+            with jax.named_scope("lane"):
+                zc = rms_norm(hc, p_l["ln1"], cfg.norm_eps)
+                oc, c_new = chunk_prefill_attention(
+                    p_l["attn"], zc, c_new, chunk["table"],
+                    chunk["start"], chunk["length"], cfg, ctx,
                 )
-            else:
-                yc, ac = mlp_apply(p_l["mlp"], z2c, ctx), zero_aux(cfg)
-            hc = hc + yc
+                hc = hc + oc
+                z2c = rms_norm(hc, p_l["ln2"], cfg.norm_eps)
+                if cfg.is_moe:
+                    yc, ac = moe_apply(
+                        p_l["moe"], z2c, cfg, ctx, placement=placement,
+                        token_mask=cvalid,
+                    )
+                else:
+                    yc, ac = mlp_apply(p_l["mlp"], z2c, ctx), zero_aux(cfg)
+                hc = hc + yc
             a_sum = jax.tree.map(jnp.add, a_sum, jax.tree.map(jnp.add, a, ac))
             return (h, hc, a_sum), c_new
 
@@ -472,9 +479,10 @@ def decode_step(
                 (params["layers"], cache["layers"]),
                 unroll=ctx.full_unroll,
             )
-            last = jnp.clip(chunk["length"] - 1, 0, n_chunk - 1)
-            xl = jax.lax.dynamic_slice_in_dim(xc, last, 1, axis=1)
-            chunk_logits = _logits(params, xl, cfg, ctx)
+            with jax.named_scope("lane"), jax.named_scope("lm_head"):
+                last = jnp.clip(chunk["length"] - 1, 0, n_chunk - 1)
+                xl = jax.lax.dynamic_slice_in_dim(xc, last, 1, axis=1)
+                chunk_logits = _logits(params, xl, cfg, ctx)
         new_cache["layers"] = new_layers
 
     elif pat == "zamba":
@@ -507,7 +515,9 @@ def decode_step(
     stats = {"expert_counts": aux["counts"]}
     if chunk_logits is not None:
         stats["chunk_logits"] = chunk_logits
-    return _logits(params, x, cfg, ctx), new_cache, stats
+    with jax.named_scope("lm_head"):
+        logits = _logits(params, x, cfg, ctx)
+    return logits, new_cache, stats
 
 
 def _zamba_decode(params, x, cache, cfg, ctx, pos):

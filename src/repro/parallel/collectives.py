@@ -328,7 +328,8 @@ def ep_moe_shardmap(
     slots_per_device: int,
     decode: bool = False,
 ):
-    """Expert-parallel MoE: dispatch -> all_to_all -> GMM -> all_to_all -> combine.
+    """Expert-parallel MoE: dispatch -> all_to_all -> GMM -> all_to_all -> combine
+    (named scopes ``dispatch``, ``expert_ffn``, ``combine``).
 
     ``slot_weights`` holds (n_total_slots, d, f) matrices sharded over the
     model axis (slot dim). Inside the per-device block each device sees its
@@ -390,6 +391,7 @@ def ep_moe_shardmap(
         kc = 1
     spc = spd // kc
 
+    @jax.named_scope("dispatch")
     def dispatch_fused(xt, slots):
         """Per-chunk rank-compacted send buffers + per-bucket metadata (no
         padding between a chunk's buckets; bucket order within a rank
@@ -434,17 +436,19 @@ def ep_moe_shardmap(
 
         # Physical slot per copy; masked tokens (expert id E sentinel from
         # moe_apply's token_mask) overflow out of every bucket.
-        slots = choose_slots(eid, slot_of_, n_rep_, sentinel=total_slots + 1)
-        if decode:
-            # Tokens are replicated across the EP axis: rank r owns
-            # idx % ep == r; unowned copies overflow out of every bucket.
-            rank = jax.lax.axis_index(axis)
-            owned = (jnp.arange(bl * sl) % ep) == rank
-            slots = jnp.where(owned[:, None], slots, total_slots + 1)
+        with jax.named_scope("dispatch"):
+            slots = choose_slots(eid, slot_of_, n_rep_, sentinel=total_slots + 1)
+            if decode:
+                # Tokens are replicated across the EP axis: rank r owns
+                # idx % ep == r; unowned copies overflow out of every bucket.
+                rank = jax.lax.axis_index(axis)
+                owned = (jnp.arange(bl * sl) % ep) == rank
+                slots = jnp.where(owned[:, None], slots, total_slots + 1)
 
         if fused:
             sends, kept_ck, keep, chunk_of, dest, posr = dispatch_fused(xt, slots)
 
+            @jax.named_scope("dispatch")
             def exchange(c):
                 recv = jax.lax.all_to_all(
                     sends[c], axis, split_axis=0, concat_axis=0, tiled=False
@@ -472,23 +476,25 @@ def ep_moe_shardmap(
                 # to the gather+scatter pair (same layout contract) per
                 # chunk when it doesn't.
                 ws = slice(c * spc, (c + 1) * spc)
-                y = registry.expert_ffn_from_rows(
-                    recv.reshape(ep * spc * seg, d),
-                    wg[ws],
-                    wu[ws],
-                    wd[ws],
-                    offsets_g,
-                    counts_g,
-                    capacity=seg,
-                    groups_per_weight=ep,
-                    enabled=True,
-                    compact_out=True,
-                    fused=True,
-                )
-                return jax.lax.all_to_all(
-                    y.reshape(ep, spc * seg, d), axis,
-                    split_axis=0, concat_axis=0, tiled=False,
-                )
+                with jax.named_scope("expert_ffn"):
+                    y = registry.expert_ffn_from_rows(
+                        recv.reshape(ep * spc * seg, d),
+                        wg[ws],
+                        wu[ws],
+                        wd[ws],
+                        offsets_g,
+                        counts_g,
+                        capacity=seg,
+                        groups_per_weight=ep,
+                        enabled=True,
+                        compact_out=True,
+                        fused=True,
+                    )
+                with jax.named_scope("combine"):
+                    return jax.lax.all_to_all(
+                        y.reshape(ep, spc * seg, d), axis,
+                        split_axis=0, concat_axis=0, tiled=False,
+                    )
 
             # Software pipeline over the chunks (trace-unrolled): chunk
             # c+1's dispatch all_to_all is issued *before* chunk c's FFN,
@@ -512,52 +518,58 @@ def ep_moe_shardmap(
             # out. A single gather + einsum keeps the per-token k-copy
             # reduction order identical to the single-shot path (bit-
             # exactness); per-chunk partial combines would re-order it.
-            back = jnp.concatenate(backs, axis=0)
-            rows = chunk_of * (ep * spc * seg) + dest * (spc * seg) + posr
-            out = combine_from_rows(
-                back.reshape(kc * ep * spc * seg, d),
-                rows.reshape(bl * sl, k), keep, w,
-            )
+            with jax.named_scope("combine"):
+                back = jnp.concatenate(backs, axis=0)
+                rows = chunk_of * (ep * spc * seg) + dest * (spc * seg) + posr
+                out = combine_from_rows(
+                    back.reshape(kc * ep * spc * seg, d),
+                    rows.reshape(bl * sl, k), keep, w,
+                )
         else:
-            bufs, pos, keep = bucket_dispatch(xt, slots, total_slots, cap)
-            # How full each outgoing bucket actually is — rides the same
-            # all_to_all so every device knows its received buckets'
-            # raggedness.
-            counts = kept_counts(slots, keep, total_slots)
-            # (total_slots, cap, d) -> exchange so each device gets its slots.
-            bufs = bufs.reshape(ep, spd, cap, d)
-            recv = jax.lax.all_to_all(
-                bufs, axis, split_axis=0, concat_axis=0, tiled=False
-            )
-            cnt = jax.lax.all_to_all(
-                counts.reshape(ep, spd), axis,
-                split_axis=0, concat_axis=0, tiled=False,
-            )
-            # recv: (ep, spd, cap, d) — axis 0 now = source rank.
-            recv = recv.transpose(1, 0, 2, 3)              # (spd, ep, cap, d)
-            cnt = cnt.transpose(1, 0)                      # (spd, ep)
+            with jax.named_scope("dispatch"):
+                bufs, pos, keep = bucket_dispatch(xt, slots, total_slots, cap)
+                # How full each outgoing bucket actually is — rides the same
+                # all_to_all so every device knows its received buckets'
+                # raggedness.
+                counts = kept_counts(slots, keep, total_slots)
+                # (total_slots, cap, d) -> exchange so each device gets its
+                # slots.
+                bufs = bufs.reshape(ep, spd, cap, d)
+                recv = jax.lax.all_to_all(
+                    bufs, axis, split_axis=0, concat_axis=0, tiled=False
+                )
+                cnt = jax.lax.all_to_all(
+                    counts.reshape(ep, spd), axis,
+                    split_axis=0, concat_axis=0, tiled=False,
+                )
+                # recv: (ep, spd, cap, d) — axis 0 now = source rank.
+                recv = recv.transpose(1, 0, 2, 3)          # (spd, ep, cap, d)
+                cnt = cnt.transpose(1, 0)                  # (spd, ep)
 
             # Local expert compute: bucket (slot e, source r) uses weight
             # row e; the ragged GMM kernels skip capacity rows past each
             # bucket's count, so FFN FLOPs track tokens actually routed
             # (fallback: folded einsums over the same layout).
-            y = registry.expert_ffn(
-                recv.reshape(spd * ep, cap, d),
-                wg,
-                wu,
-                wd,
-                group_sizes=cnt.reshape(-1),
-                groups_per_weight=ep,
-                enabled=use_kernels,
-            )
-            y = y.reshape(spd, ep, cap, d).transpose(1, 0, 2, 3)
-            back = jax.lax.all_to_all(
-                y, axis, split_axis=0, concat_axis=0, tiled=False
-            )
-            back = back.reshape(total_slots, cap, d)
-            out = bucket_combine(back, slots, pos, keep, w)
+            with jax.named_scope("expert_ffn"):
+                y = registry.expert_ffn(
+                    recv.reshape(spd * ep, cap, d),
+                    wg,
+                    wu,
+                    wd,
+                    group_sizes=cnt.reshape(-1),
+                    groups_per_weight=ep,
+                    enabled=use_kernels,
+                )
+            with jax.named_scope("combine"):
+                y = y.reshape(spd, ep, cap, d).transpose(1, 0, 2, 3)
+                back = jax.lax.all_to_all(
+                    y, axis, split_axis=0, concat_axis=0, tiled=False
+                )
+                back = back.reshape(total_slots, cap, d)
+                out = bucket_combine(back, slots, pos, keep, w)
         if decode:
-            out = jax.lax.psum(out, axis)  # gather owners' results everywhere
+            with jax.named_scope("combine"):
+                out = jax.lax.psum(out, axis)  # gather owners' results everywhere
         return out.reshape(bl, sl, d)
 
     bspec = ctx.batch_spec
@@ -605,7 +617,8 @@ def ep_moe_local(
     identity. This is what lets the NI-Balancer run for real on one
     process (``ServeConfig.virtual_ep``): replica routing, migrations and
     evacuations move actual weight rows between slot rows; only the
-    inter-device hop is notional."""
+    inter-device hop is notional. Named scopes: ``dispatch``,
+    ``expert_ffn``, ``combine``."""
     b, s, d = x.shape
     k = expert_ids.shape[-1]
     n = b * s
@@ -613,13 +626,14 @@ def ep_moe_local(
     eid = expert_ids.reshape(n, k)
     w = weights.reshape(n, k)
     cap = bucket_capacity(n, k, capacity_factor, total_slots)
-    slots = choose_slots(eid, slot_of, n_replicas, sentinel=total_slots + 1)
-    bufs, pos, keep = bucket_dispatch(xt, slots, total_slots, cap)
-    counts = kept_counts(slots, keep, total_slots)
-    # Bucket rows rounded up to ROW_ALIGN so the grouped kernels' row tiles
-    # meet Mosaic's block rule (can_gmm); the extra rows are dead (past
-    # every count) and sliced off before the combine.
-    bufs = jnp.pad(bufs, ((0, 0), (0, round_up(cap) - cap), (0, 0)))
+    with jax.named_scope("dispatch"):
+        slots = choose_slots(eid, slot_of, n_replicas, sentinel=total_slots + 1)
+        bufs, pos, keep = bucket_dispatch(xt, slots, total_slots, cap)
+        counts = kept_counts(slots, keep, total_slots)
+        # Bucket rows rounded up to ROW_ALIGN so the grouped kernels' row
+        # tiles meet Mosaic's block rule (can_gmm); the extra rows are dead
+        # (past every count) and sliced off before the combine.
+        bufs = jnp.pad(bufs, ((0, 0), (0, round_up(cap) - cap), (0, 0)))
     # ep_chunks: the local path has no all_to_all to hide, but it is the
     # substrate the virtual-EP serving/chaos tests run on — chunking the
     # grouped FFN the same way keeps the chunked program on the hot path
@@ -629,19 +643,21 @@ def ep_moe_local(
     if kc > 1:
         validate_ep_chunks(kc, total_slots, where="ep_moe_local total_slots")
     spt = total_slots // kc
-    ys = [
-        registry.expert_ffn(
-            bufs[c * spt : (c + 1) * spt],
-            slot_weights["w_gate"][c * spt : (c + 1) * spt],
-            slot_weights["w_up"][c * spt : (c + 1) * spt],
-            slot_weights["w_down"][c * spt : (c + 1) * spt],
-            group_sizes=counts[c * spt : (c + 1) * spt],
-            enabled=ctx.kernels_on,
-        )
-        for c in range(kc)
-    ]
-    y = ys[0] if kc == 1 else jnp.concatenate(ys, axis=0)
-    out = bucket_combine(y[:, :cap], slots, pos, keep, w)
+    with jax.named_scope("expert_ffn"):
+        ys = [
+            registry.expert_ffn(
+                bufs[c * spt : (c + 1) * spt],
+                slot_weights["w_gate"][c * spt : (c + 1) * spt],
+                slot_weights["w_up"][c * spt : (c + 1) * spt],
+                slot_weights["w_down"][c * spt : (c + 1) * spt],
+                group_sizes=counts[c * spt : (c + 1) * spt],
+                enabled=ctx.kernels_on,
+            )
+            for c in range(kc)
+        ]
+        y = ys[0] if kc == 1 else jnp.concatenate(ys, axis=0)
+    with jax.named_scope("combine"):
+        out = bucket_combine(y[:, :cap], slots, pos, keep, w)
     return out.reshape(b, s, d)
 
 

@@ -63,11 +63,13 @@ def _copy_row_slice(w, src_slot, dst_slot, lo, *, rows: int):
     updates the live buffer instead of round-tripping every expert weight
     (the old full-tensor ``.at[:, slot].set(...)`` functional update).
     Slot ids and ``lo`` are traced scalars, so every slice of every
-    migration reuses one compiled program per (shape, chunk)."""
-    blk = jax.lax.dynamic_slice(
-        w, (0, src_slot, lo, 0), (w.shape[0], 1, rows, w.shape[3])
-    )
-    return jax.lax.dynamic_update_slice(w, blk, (0, dst_slot, lo, 0))
+    migration reuses one compiled program per (shape, chunk). Its ops carry
+    the named scope ``migration``."""
+    with jax.named_scope("migration"):
+        blk = jax.lax.dynamic_slice(
+            w, (0, src_slot, lo, 0), (w.shape[0], 1, rows, w.shape[3])
+        )
+        return jax.lax.dynamic_update_slice(w, blk, (0, dst_slot, lo, 0))
 
 
 def _i32(x: int):

@@ -55,6 +55,7 @@ import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime import faults as F
 
@@ -355,25 +356,29 @@ class RequestScheduler:
                 if self._poison is None:
                     self._poison = {i for i, r in enumerate(self.slots) if r}
 
-    def _admit_ready(self) -> None:
+    def _admit_ready(self) -> list[int]:
+        """Admit arrived requests in FIFO order; returns their ids."""
+        admitted: list[int] = []
         while self.queue:
             if self.chunk and self._prefilling is not None:
                 # One admission in flight at a time: the prefill lane is a
                 # single chunk per tick, and strict FIFO means nobody may
                 # overtake the head mid-prefill anyway.
-                return
+                break
             free = self._free_slots()
             if not free:
-                return
+                break
             # Strict FIFO among arrived requests: the earliest-queued
             # arrived request either admits or blocks admission this tick.
             head = next(
                 (r for r in self.queue if r.arrival <= self.step_no), None
             )
             if head is None or not self._admissible(head):
-                return
+                break
             self.queue.remove(head)
             self._admit(head, free[0])
+            admitted.append(head.rid)
+        return admitted
 
     def _ensure_headroom(self) -> None:
         """Preempt until this step's lazy page growth cannot exhaust the
@@ -429,52 +434,73 @@ class RequestScheduler:
         Snapshot/crash handling comes first — before faults, admission or
         decode — so a snapshot always captures a clean tick boundary (the
         end of the previous tick) and the faults of the crash tick re-fire
-        exactly once after a restore."""
-        if (
-            self.cfg.snapshot_every
-            and self.step_no
-            and self.step_no % self.cfg.snapshot_every == 0
-        ):
-            self.save_snapshot(self.cfg.snapshot_path or None)
-        crash = next(
-            (
-                f
-                for f in self.faults.at(self.step_no)
-                if f.kind == F.CRASH_RESTART
-            ),
-            None,
-        )
-        if crash is not None:
-            snap = self.save_snapshot(crash.path or None)
-            raise F.SimulatedCrash(self.step_no, snap, crash.path)
-        self._apply_faults()
-        self._admit_ready()
-        self._ensure_headroom()
-        self._drain_migrations()
-        finished: list[Request] = []
-        if self._live():
-            chunk = None
-            chunk_n = 0
-            pf = self._prefilling
-            if pf is not None:
-                # One fixed-size chunk of the head-of-admission request's
-                # context rides this tick's step (right-padded — the shape
-                # is jit-stable; `length` marks the valid rows).
-                ctx_tokens = np.concatenate(
-                    [pf.prompt, np.asarray(pf.tokens_out, np.int32)]
-                )
-                chunk_n = min(self.chunk, len(ctx_tokens) - pf.prefill_pos)
-                buf = np.zeros(self.chunk, np.int32)
-                buf[:chunk_n] = ctx_tokens[
-                    pf.prefill_pos : pf.prefill_pos + chunk_n
-                ]
-                chunk = self.server.chunk_operand(
-                    pf.slot, buf, pf.prefill_pos, chunk_n
-                )
-            logits, self.cache = self.server.decode(
-                jnp.asarray(self.next_tok), self.cache, chunk=chunk
+        exactly once after a restore.
+
+        Profiler spans: ``sched.step`` covers the tick; inside it
+        ``sched.admit`` (faults, admission, headroom, idle-tick migrations;
+        one ``rid`` argument per admitted request), ``sched.chunk`` (the
+        lane operand), the server's ``server.decode`` and ``sched.sample``
+        (logits to the host, then the tokens)."""
+        with TraceAnnotation("sched.step"):
+            if (
+                self.cfg.snapshot_every
+                and self.step_no
+                and self.step_no % self.cfg.snapshot_every == 0
+            ):
+                self.save_snapshot(self.cfg.snapshot_path or None)
+            crash = next(
+                (
+                    f
+                    for f in self.faults.at(self.step_no)
+                    if f.kind == F.CRASH_RESTART
+                ),
+                None,
             )
-            rows = np.asarray(logits[:, -1])                 # (B, V)
+            if crash is not None:
+                snap = self.save_snapshot(crash.path or None)
+                raise F.SimulatedCrash(self.step_no, snap, crash.path)
+            with TraceAnnotation("sched.admit") as span:
+                self._apply_faults()
+                for rid in self._admit_ready():
+                    span.set_metadata(rid=rid)
+                self._ensure_headroom()
+                self._drain_migrations()
+            finished = self._decode_tick() if self._live() else []
+            self._poison = None
+            self.step_no += 1
+            return finished
+
+    def _chunk_operand(self, pf: Request) -> tuple[dict, int]:
+        """One fixed-size chunk of the head-of-admission request's context
+        for this tick's step (right-padded — the shape is jit-stable;
+        ``length`` marks the valid rows). Returns the operand and the
+        number of valid tokens."""
+        ctx_tokens = np.concatenate(
+            [pf.prompt, np.asarray(pf.tokens_out, np.int32)]
+        )
+        chunk_n = min(self.chunk, len(ctx_tokens) - pf.prefill_pos)
+        buf = np.zeros(self.chunk, np.int32)
+        buf[:chunk_n] = ctx_tokens[pf.prefill_pos : pf.prefill_pos + chunk_n]
+        chunk = self.server.chunk_operand(pf.slot, buf, pf.prefill_pos, chunk_n)
+        return chunk, chunk_n
+
+    def _decode_tick(self) -> list[Request]:
+        """The decode step over the live batch (with the lane's chunk, if
+        one is in flight), then one token per DECODING row and, on a final
+        chunk, the admitted request's first token."""
+        finished: list[Request] = []
+        chunk = None
+        chunk_n = 0
+        pf = self._prefilling
+        if pf is not None:
+            with TraceAnnotation("sched.chunk", rid=pf.rid):
+                chunk, chunk_n = self._chunk_operand(pf)
+        logits, self.cache = self.server.decode(
+            jnp.asarray(self.next_tok), self.cache, chunk=chunk
+        )
+        with TraceAnnotation("sched.sample"):
+            with TraceAnnotation("sched.logits_to_host"):
+                rows = np.asarray(logits[:, -1])             # (B, V)
             poison = self._poison or set()
             if poison:
                 rows = rows.copy()
@@ -509,8 +535,6 @@ class RequestScheduler:
                         self._prefilling = None
                         if self._push_token(pf, int(np.argmax(crow))):
                             finished.append(pf)
-        self._poison = None
-        self.step_no += 1
         return finished
 
     def run(self, max_steps: int | None = None) -> dict[int, np.ndarray]:
@@ -582,9 +606,6 @@ class RequestScheduler:
             "queue_depth": len(self.queue),
             "prefill_backlog": backlog,
             "n_preempted": self.n_preempted,
-            # Static dispatch-pipeline depth of the step program (ops
-            # visibility: 1 = single-shot EP dispatch, K = chunked overlap).
-            "ep_chunks": self.server.scfg.ep_chunks,
             "max_ttft_ticks": max(ttfts, default=None),
             "max_stall_ticks": max(
                 (r.max_stall for r in self.requests), default=0

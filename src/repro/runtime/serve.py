@@ -42,6 +42,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -768,7 +769,45 @@ class Server:
         """One fused step. With ``ServeConfig(prefill_chunk=N)`` a chunk
         operand is ALWAYS passed to the jitted step — ``chunk=None`` here
         substitutes the no-op chunk — so idle, decode-only and decode+chunk
-        ticks compile to one program per shape."""
+        ticks compile to one program per shape.
+
+        Profiler spans: ``server.decode`` covers the call; inside it
+        ``server.prepare`` (operands, pages, capacity, layout, row mask),
+        ``server.migrate`` (commits and this tick's slice copies),
+        ``server.dispatch`` (the step's launch and the host mirrors after
+        it), ``server.counts`` (the expert counts to the host: the first
+        wait on the step) and ``server.balance`` (balancer update and
+        plan)."""
+        with TraceAnnotation("server.decode"):
+            with TraceAnnotation("server.prepare"):
+                chunk, cache, slot_mask = self._prepare_step(token, cache, chunk)
+            placement = None
+            if self.use_balancer:
+                with TraceAnnotation("server.migrate"):
+                    # Step boundary: commit migrations whose last slice
+                    # landed (the atomic routing-table swap), then issue
+                    # this tick's weight slices — dispatched before the
+                    # step so the copy overlaps the decode compute below.
+                    self.drain_migrations()
+                    placement = self.table.device_view()
+            with TraceAnnotation("server.dispatch"):
+                logits, cache, stats = self._decode(
+                    self.params, token, cache, placement=placement,
+                    slot_mask=slot_mask, chunk=chunk,
+                )
+                cache = self._advance(cache, stats)
+            if self.use_balancer:
+                with TraceAnnotation("server.counts"):
+                    counts = np.asarray(stats["expert_counts"])
+                with TraceAnnotation("server.balance"):
+                    self.state.observe(counts)
+                    self._maybe_balance(counts)
+            return logits, cache
+
+    def _prepare_step(self, token, cache, chunk):
+        """The step's operands: the lane's chunk (the no-op chunk when none
+        rides), the next write's pages, the capacity check, the cache in
+        the step's layout and the live-row mask."""
         if self.scfg.prefill_chunk:
             if chunk is None:
                 chunk = self.noop_chunk()
@@ -780,7 +819,6 @@ class Server:
             )
         if self._pos is None:   # cache primed outside this Server
             self._pos = int(cache["pos"])
-        pos = self._pos
         windowed = bool(self.cfg.sliding_window or 0)
         if self.scfg.paged:
             cache = self._ensure_pages(cache)   # also syncs _written
@@ -796,20 +834,13 @@ class Server:
                         f"decode past capacity={cap} for request(s) {full} "
                         f"(cache full): release them or raise max_seq"
                     )
-        elif not windowed and pos >= self.scfg.max_seq:
+        elif not windowed and self._pos >= self.scfg.max_seq:
             # Dense caches used to clobber the last slot silently here;
             # both layouts now freeze at capacity and serving refuses.
             raise RuntimeError(
                 f"decode past max_seq={self.scfg.max_seq} (cache full, "
-                f"pos={pos}): release the request or raise max_seq"
+                f"pos={self._pos}): release the request or raise max_seq"
             )
-        if self.use_balancer:
-            # Step boundary: commit migrations whose last slice landed (the
-            # atomic routing-table swap), then issue this tick's weight
-            # slices — dispatched before the step so the copy overlaps the
-            # decode compute below.
-            self.drain_migrations()
-        placement = self.table.device_view() if self.use_balancer else None
         shardings = _paged_cache_shardings(self.cfg, cache, self.ctx)
         if shardings is not None:
             # Host-built leaves (fresh cache, rebuilt tables) join the
@@ -828,10 +859,11 @@ class Server:
             live = np.ones(token.shape[0], bool)
             live[sorted(self._released)] = False
             slot_mask = jnp.asarray(live)
-        logits, cache, stats = self._decode(
-            self.params, token, cache, placement=placement,
-            slot_mask=slot_mask, chunk=chunk,
-        )
+        return chunk, cache, slot_mask
+
+    def _advance(self, cache, stats):
+        """Host mirrors after the step: the lane's logits, the written
+        lengths, the position, the tick; released rows' lengths pinned."""
         # Chunk-lane logits (last valid chunk position): on the final chunk
         # of an admission these emit the request's first token. Host mirror
         # — the scheduler reads it right after the step it drove.
@@ -853,13 +885,9 @@ class Server:
                         "lengths": lengths.at[:, idx].set(0),
                     },
                 }
-        self._pos = pos + 1
+        self._pos += 1
         self.t += 1
-        if self.use_balancer:
-            counts = np.asarray(stats["expert_counts"])
-            self.state.observe(counts)
-            self._maybe_balance(counts)
-        return logits, cache
+        return cache
 
     def generate(self, prompt, n_tokens: int, embeds=None):
         logits, cache = self.prefill(prompt, embeds=embeds)

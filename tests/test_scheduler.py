@@ -316,7 +316,6 @@ def test_chaos_parity_chunked_dispatch():
     sched = _chaos_run(seed=14, ep_chunks=3, pool_pages=7)
     assert _pool_preemptions(sched) > 0
     assert sched.server.scfg.ep_chunks == 3
-    assert sched.stats()["ep_chunks"] == 3   # ops visibility
 
 
 @pytest.mark.slow

@@ -1,13 +1,24 @@
 """Count-aware (ragged) grouped matmul Pallas kernels.
 
-Megablocks-style refinement of ``gmm``/``gmm_dual_act``: the per-group token
-counts (``group_sizes``, int32 ``(G,)``) ride in as a scalar-prefetch operand
-(SMEM), and each row-tile checks ``mi * bm < count`` before touching the MXU.
-Row-tiles entirely past a group's count skip both matmuls; partially-filled
-tiles mask their tail rows to zero on the final K step. MXU FLOPs therefore
-scale with ``sum(ceil(count / bm) * bm)`` ≈ tokens actually routed, not
-``G * capacity`` — on the skewed routing distributions the paper targets
-(fig. 6) that's the bulk of the padded EP FFN cost.
+``gmm_ragged`` / ``gmm_dual_act_ragged`` take bucket-padded ``(G, C, K)``
+rows whose per-group token counts (``group_sizes``, int32 ``(G,)``) ride in
+as a scalar-prefetch operand (SMEM). They stream weights, not tiles: the
+grid is ``(G, C/bc, N/bn)`` with the column panels innermost, each step
+multiplies the group's resident ``(bc, K)`` rows by full-contraction
+``(K, bn)`` weight panels, and the live rows go through the MXU in
+``bm``-row tiles (rows past the count are zero, tiles past it skip the
+MXU). Blocks come from the operand shapes and a VMEM budget
+(``ragged_blocks``): at DBRX widths a call is a few hundred grid steps
+that each move megabytes, so the per-step overhead is noise and the call
+runs at the HBM rate of one read of each live group's weights.
+
+An empty group (or a row block past its count) moves nothing: the input
+and weight index maps read the scalar-prefetched pins (``ragged_pins``)
+and point a dead step at blocks the pipeline already holds or fetches next,
+so Pallas issues no copy for it. An all-empty call reads one block. MXU
+FLOPs scale with ``sum(ceil(count / bm) * bm)``, weight bytes with the
+live groups: on the skewed routing the paper targets (fig. 6), and for a
+prefill lane with no chunk, that is the bulk of the padded FFN cost.
 
 ``groups_per_weight`` (gpw) lets ``gpw`` consecutive x-groups share one
 weight row — the layout both MoE paths produce after flattening:
@@ -17,9 +28,8 @@ weight row — the layout both MoE paths produce after flattening:
 * ESP local buckets: ``(E, n_batch_groups, cap, d)`` flattens to
   ``G = E * n_groups`` groups, weight row ``gi // n_groups``.
 
-VMEM per step matches the padded kernels (the scalar counts live in SMEM);
-the grid is identical, so the only cost of raggedness is the SMEM read and
-the per-tile predicate.
+Consecutive live groups that share a weight row each fetch its panels:
+the column loop restarts for every group.
 
 ``gmm_gather`` / ``gmm_dual_act_gather`` go one step further and fuse the
 *dispatch* into the kernel prologue: instead of consuming pre-packed
@@ -92,32 +102,162 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.tiling import ROW_ALIGN, fit_block, round_up
 
 
-def _ragged_kernel(gs_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int, bm: int):
-    gi = pl.program_id(0)
-    mi = pl.program_id(1)
-    k = pl.program_id(3)
-    count = gs_ref[gi]
-    live = mi * bm < count
+# Scoped VMEM the two ragged kernels ask Mosaic for (``vmem_limit_bytes``;
+# the default scope is 16 MiB), and what the block rule may plan for. Set
+# from the TPU compiler on a described v5e: it accepts a scope of the
+# core's whole 128 MiB, and a plan that ``ragged_vmem_bytes`` puts 1 MiB
+# under the scope (a 112 MiB plan compiles in a 120 MiB scope; a 144.6 MiB
+# plan is refused, "Used 143.94M of 128.00M"). The budget keeps 16 MiB of
+# the core for Mosaic's own scratch.
+RAGGED_VMEM_LIMIT = 128 * 2**20
+RAGGED_VMEM_BUDGET = 112 * 2**20
+# Row tile of the MXU pass: live rows are computed in tiles of this height,
+# so a bucket's rows past its count cost no MXU work beyond one tile.
+RAGGED_ROW_TILE = 32
+# Widest weight panel: at DBRX widths a (6144, 1536) bf16 panel is 18 MiB,
+# tens of microseconds of HBM time a grid step, so the per-step overhead
+# (~0.3 us) is noise, while the panels still double-buffer inside the
+# budget at every bucket height. On a v5e the pair ran at 674-702 GB/s
+# with panels of 512 or 1536 and row tiles of 32, 64 or 128; 1536 columns
+# and 32 rows was the fastest by 2%.
+RAGGED_MAX_COLS = 1536
 
-    @pl.when(k == 0)
-    def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(live)
-    def _():
-        acc_ref[...] += jax.lax.dot_general(
-            x_ref[0],
-            w_ref[0],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+def ragged_vmem_bytes(rows, k, bn, bm, itemsize, n_w):
+    """VMEM a ragged kernel's step holds: the double-buffered ``(rows, k)``
+    input block, ``n_w`` ``(k, bn)`` weight panels and the ``(rows, bn)``
+    output block, plus a row tile and its fp32 products."""
+    blocks = 2 * itemsize * (rows * k + n_w * k * bn + rows * bn)
+    return blocks + itemsize * bm * k + 4 * bm * bn * (n_w + 1)
 
-    @pl.when(k == nk - 1)
+
+def ragged_blocks(c, k, n, itemsize, n_w):
+    """Blocks ``(bm, bc, bn)`` for ``(G, c, k) @ n_w x (G', k, n)``.
+
+    ``bm``: the row tile, the largest divisor of ``c`` up to
+    ``RAGGED_ROW_TILE`` that fills whole packed sublane tiles (8 rows of
+    fp32, 16 of bf16), else ``c``. ``bc``: the rows held resident per step,
+    all ``c`` where they fit, so a group's rows are read once for all its
+    column panels. ``bn``: the widest column panel up to
+    ``RAGGED_MAX_COLS`` (``n`` itself, or a multiple of 128 dividing it)
+    whose blocks fit ``RAGGED_VMEM_BUDGET``. The contraction is never split:
+    each panel is a full ``(k, bn)`` slab, fetched once per live step.
+    """
+    align = 32 // itemsize
+    bm = next(
+        (t for t in range(min(c, RAGGED_ROW_TILE), 0, -1)
+         if c % t == 0 and t % align == 0),
+        c,
+    )
+    cols = [n] if n <= RAGGED_MAX_COLS else []
+    cols += [t for t in range(RAGGED_MAX_COLS, 0, -128) if t < n and n % t == 0]
+    cols = cols or [fit_block(n, RAGGED_MAX_COLS)]  # interpret-mode widths
+    for bc in (t for t in range(c, 0, -bm) if c % t == 0):
+        for bn in cols:
+            if ragged_vmem_bytes(bc, k, bn, bm, itemsize, n_w) <= RAGGED_VMEM_BUDGET:
+                return bm, bc, bn
+    raise ValueError(f"no ragged GMM blocks fit VMEM for c={c} k={k} n={n}")
+
+
+def ragged_pins(group_sizes, c, bc, nj):
+    """Where each ``(group, row block)`` step reads its blocks.
+
+    Step ``s = g * (c // bc) + r`` is live when ``r * bc < count[g]`` and
+    reads its own blocks. A dead step reads the blocks the pipeline holds
+    anyway, so Pallas issues no copy for it: the next live step's first
+    blocks, or after the last live step that step's last blocks (one
+    fixed block when nothing is live). Returns ``(src, col)``: the step
+    whose input and weight blocks step ``s`` reads, and the weight panel
+    it pins to (``-1``: a live step walks its own panels).
+    """
+    nr = c // bc
+    s = group_sizes.shape[0] * nr
+    idx = jnp.arange(s, dtype=jnp.int32)
+    live = (idx % nr) * bc < jnp.repeat(group_sizes.astype(jnp.int32), nr)
+    nxt = jax.lax.cummin(jnp.where(live, idx, s), reverse=True)
+    prv = jax.lax.cummax(jnp.where(live, idx, -1))
+    ahead = nxt < s
+    src = jnp.where(ahead, nxt, jnp.maximum(prv, 0))
+    col = jnp.where(live, -1, jnp.where(ahead | (prv < 0), 0, nj - 1))
+    return src.astype(jnp.int32), col.astype(jnp.int32)
+
+
+def ragged_block_maps(nr, gpw):
+    """Index maps of the input block and the weight panels at grid step
+    ``(gi, r, j)``, reading ``ragged_pins``' ``src`` and ``col``."""
+
+    def x_map(gi, r, j, gs, src, col):
+        s = src[gi * nr + r]
+        return s // nr, s % nr, 0
+
+    def w_map(gi, r, j, gs, src, col):
+        s = gi * nr + r
+        return src[s] // nr // gpw, 0, jnp.where(col[s] < 0, j, col[s])
+
+    return x_map, w_map
+
+
+def _fill_rows(gs_ref, o_ref, tile, *, bm):
+    """Write one ``(bc, bn)`` output block of step ``(g, r)``: the live
+    rows one ``bm`` tile at a time (``tile(r0)`` gives their fp32 values),
+    rows past the group's count zero. Tiles past the count issue no MXU
+    pass, and a dead step only writes zeros."""
+    bc = o_ref.shape[1]
+    rows = jnp.clip(gs_ref[pl.program_id(0)] - pl.program_id(1) * bc, 0, bc)
+
+    @pl.when(rows < bc)
     def _():
-        rows = mi * bm + jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 0)
-        o_ref[0, ...] = jnp.where(rows < count, acc_ref[...], 0.0).astype(
-            o_ref.dtype
-        )
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def body(t, carry):
+        r0 = pl.multiple_of(t * bm, bm)
+        y = tile(r0)
+        ids = r0 + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0)
+        o_ref[0, pl.ds(r0, bm), :] = jnp.where(ids < rows, y, 0.0).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(rows, bm), body, 0)
+
+
+def _ragged_call(kernel, x, weights, group_sizes, gpw, interpret):
+    """Shared ``pallas_call`` of the two ragged kernels: grid
+    ``(G, c // bc, n // bn)`` with the column panels innermost; inputs and
+    weight panels follow ``ragged_pins``; outputs ``(G, c, n)``."""
+    g, c, k = x.shape
+    n = weights[0].shape[-1]
+    assert g == weights[0].shape[0] * gpw, (g, weights[0].shape, gpw)
+    bm, bc, bn = ragged_blocks(c, k, n, x.dtype.itemsize, len(weights))
+    nr, nj = c // bc, n // bn
+    src, col = ragged_pins(group_sizes, c, bc, nj)
+
+    x_map, w_map = ragged_block_maps(nr, gpw)
+    spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(g, nr, nj),
+        in_specs=[pl.BlockSpec((1, bc, k), x_map)]
+        + [pl.BlockSpec((1, k, bn), w_map) for _ in weights],
+        out_specs=pl.BlockSpec((1, bc, bn), lambda gi, r, j, *_: (gi, r, j)),
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, bm=bm),
+        grid_spec=spec,
+        out_shape=jax.ShapeDtypeStruct((g, c, n), x.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=RAGGED_VMEM_LIMIT),
+        interpret=interpret,
+    )(group_sizes.astype(jnp.int32), src, col, x, *weights)
+
+
+def _dot(a, b):
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _ragged_kernel(gs_ref, src_ref, col_ref, x_ref, w_ref, o_ref, *, bm):
+    _fill_rows(
+        gs_ref, o_ref, lambda r0: _dot(x_ref[0, pl.ds(r0, bm), :], w_ref[0]),
+        bm=bm,
+    )
 
 
 def gmm_ragged(
@@ -126,65 +266,22 @@ def gmm_ragged(
     group_sizes: jax.Array,  # (G,) int32 — valid leading rows per group
     *,
     groups_per_weight: int = 1,
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """y[g, :count_g] = x[g, :count_g] @ w[g // gpw]; tail rows are zero."""
-    g, c, d = x.shape
-    f = w.shape[-1]
-    gpw = groups_per_weight
-    assert g == w.shape[0] * gpw, (g, w.shape, gpw)
-    bm, bn, bk = fit_block(c, bm), fit_block(f, bn), fit_block(d, bk)
-    nk = d // bk
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(g, c // bm, f // bn, nk),
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda gi, i, j, k, gs: (gi, i, k)),
-            pl.BlockSpec((1, bk, bn), lambda gi, i, j, k, gs: (gi // gpw, k, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda gi, i, j, k, gs: (gi, i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    return _ragged_call(
+        _ragged_kernel, x, (w,), group_sizes, groups_per_weight, interpret
     )
-    return pl.pallas_call(
-        functools.partial(_ragged_kernel, nk=nk, bm=bm),
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((g, c, f), x.dtype),
-        interpret=interpret,
-    )(group_sizes.astype(jnp.int32), x, w)
 
 
 def _ragged_dual_kernel(
-    gs_ref, x_ref, wg_ref, wu_ref, o_ref, accg_ref, accu_ref, *, nk: int, bm: int
+    gs_ref, src_ref, col_ref, x_ref, wg_ref, wu_ref, o_ref, *, bm
 ):
-    gi = pl.program_id(0)
-    mi = pl.program_id(1)
-    k = pl.program_id(3)
-    count = gs_ref[gi]
-    live = mi * bm < count
+    def tile(r0):
+        xt = x_ref[0, pl.ds(r0, bm), :]
+        return jax.nn.silu(_dot(xt, wg_ref[0])) * _dot(xt, wu_ref[0])
 
-    @pl.when(k == 0)
-    def _():
-        accg_ref[...] = jnp.zeros_like(accg_ref)
-        accu_ref[...] = jnp.zeros_like(accu_ref)
-
-    @pl.when(live)
-    def _():
-        dims = (((1,), (0,)), ((), ()))
-        accg_ref[...] += jax.lax.dot_general(
-            x_ref[0], wg_ref[0], dims, preferred_element_type=jnp.float32
-        )
-        accu_ref[...] += jax.lax.dot_general(
-            x_ref[0], wu_ref[0], dims, preferred_element_type=jnp.float32
-        )
-
-    @pl.when(k == nk - 1)
-    def _():
-        rows = mi * bm + jax.lax.broadcasted_iota(jnp.int32, accg_ref.shape, 0)
-        h = jax.nn.silu(accg_ref[...]) * accu_ref[...]
-        o_ref[0, ...] = jnp.where(rows < count, h, 0.0).astype(o_ref.dtype)
+    _fill_rows(gs_ref, o_ref, tile, bm=bm)
 
 
 def gmm_dual_act_ragged(
@@ -194,38 +291,13 @@ def gmm_dual_act_ragged(
     group_sizes: jax.Array,
     *,
     groups_per_weight: int = 1,
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
     """h[g] = silu(x@wg) * (x@wu) on the first count_g rows; tail is zero."""
-    g, c, d = x.shape
-    f = wg.shape[-1]
-    gpw = groups_per_weight
-    assert g == wg.shape[0] * gpw, (g, wg.shape, gpw)
-    bm, bn, bk = fit_block(c, bm), fit_block(f, bn), fit_block(d, bk)
-    nk = d // bk
-    spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(g, c // bm, f // bn, nk),
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda gi, i, j, k, gs: (gi, i, k)),
-            pl.BlockSpec((1, bk, bn), lambda gi, i, j, k, gs: (gi // gpw, k, j)),
-            pl.BlockSpec((1, bk, bn), lambda gi, i, j, k, gs: (gi // gpw, k, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bm, bn), lambda gi, i, j, k, gs: (gi, i, j)),
-        scratch_shapes=[
-            pltpu.VMEM((bm, bn), jnp.float32),
-            pltpu.VMEM((bm, bn), jnp.float32),
-        ],
+    return _ragged_call(
+        _ragged_dual_kernel, x, (wg, wu), group_sizes, groups_per_weight,
+        interpret,
     )
-    return pl.pallas_call(
-        functools.partial(_ragged_dual_kernel, nk=nk, bm=bm),
-        grid_spec=spec,
-        out_shape=jax.ShapeDtypeStruct((g, c, f), x.dtype),
-        interpret=interpret,
-    )(group_sizes.astype(jnp.int32), x, wg, wu)
 
 
 # ---------------------------------------------------------------------------

@@ -23,13 +23,18 @@ import pytest
 from repro.configs import get_config, smoke
 from repro.kernels import registry
 from repro.kernels.gmm.ops import expert_ffn_ragged, gmm_ragged_op
-from repro.kernels.gmm.ragged import gmm_dual_act_ragged
+from repro.kernels.gmm import ragged
+from repro.kernels.gmm.ragged import (
+    gmm_dual_act_ragged,
+    ragged_block_maps,
+    ragged_blocks,
+    ragged_pins,
+)
 from repro.kernels.gmm.ref import (
     expert_ffn_ragged_ref,
     gmm_ragged_ref,
     gmm_ref,
 )
-from repro.kernels.tiling import fit_block
 from repro.models import attention as A
 from repro.models.moe import moe_dense, moe_ep, moe_esp, moe_init
 from repro.parallel.ctx import ParallelCtx
@@ -68,23 +73,28 @@ def test_gmm_ragged_matches_ref(g, c, d, f, counts):
     out = gmm_ragged_op(x, w, gs)
     ref = gmm_ragged_ref(x, w, gs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
-    # Kept rows agree with a dense oracle bit-for-bit when the K dim fits
-    # one accumulation tile and the oracle issues the kernel's own dots:
-    # one (C, D) @ (D, bn) product per output column block. (A single
+    # Kept rows agree with a dense oracle bit-for-bit when the oracle issues
+    # the kernel's own dots: one (bm, D) @ (D, bn) product per row tile and
+    # column panel, with the blocks the kernel's rule picks. (A single
     # (C, D) @ (D, F) einsum is free to pick another fp32 summation order
     # on the CPU backend, so it is not a bitwise oracle.)
-    if d <= 128:
-        bn = fit_block(f, 128)
-        dense = np.concatenate(
-            [
-                np.asarray(gmm_ref(x, w[:, :, j : j + bn]))
-                for j in range(0, f, bn)
-            ],
-            axis=2,
-        )
-        outn = np.asarray(out)
-        for gi, cnt in enumerate(counts):
-            np.testing.assert_array_equal(outn[gi, :cnt], dense[gi, :cnt])
+    bm, _, bn = ragged_blocks(c, d, f, x.dtype.itemsize, 1)
+    dense = np.concatenate(
+        [
+            np.concatenate(
+                [
+                    np.asarray(gmm_ref(x[:, r : r + bm], w[:, :, j : j + bn]))
+                    for j in range(0, f, bn)
+                ],
+                axis=2,
+            )
+            for r in range(0, c, bm)
+        ],
+        axis=1,
+    )
+    outn = np.asarray(out)
+    for gi, cnt in enumerate(counts):
+        np.testing.assert_array_equal(outn[gi, :cnt], dense[gi, :cnt])
     # Rows past each group's count are exactly zero.
     outn = np.asarray(out)
     for gi, cnt in enumerate(counts):
@@ -148,6 +158,163 @@ def test_expert_ffn_ragged_end_to_end():
     out = expert_ffn_ragged(x, wg, wu, wd, gs, groups_per_weight=gpw)
     ref = expert_ffn_ragged_ref(x, wg, wu, wd, gs, gpw)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the weight-streaming schedule: row tiles, empty groups, the block rule
+# ---------------------------------------------------------------------------
+
+def _run(kind, x, ws, gs, gpw=1):
+    if kind == "gmm":
+        return ragged.gmm_ragged(x, ws[0], gs, groups_per_weight=gpw, interpret=True)
+    return gmm_dual_act_ragged(
+        x, ws[0], ws[1], gs, groups_per_weight=gpw, interpret=True
+    )
+
+
+def _oracle(kind, x, ws, gs, gpw=1):
+    if kind == "gmm":
+        return gmm_ragged_ref(x, ws[0], gs, groups_per_weight=gpw)
+    wg, wu = (jnp.repeat(w, gpw, axis=0) for w in ws)
+    mask = (jnp.arange(x.shape[1])[None, :] < gs[:, None])[..., None]
+    return (jax.nn.silu(gmm_ref(x, wg)) * gmm_ref(x, wu)) * mask
+
+
+def _operands(g, c, d, f, gpw=1, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jax.random.normal(ks[0], (g, c, d))
+    ws = tuple(jax.random.normal(k, (g // gpw, d, f)) * 0.1 for k in ks[1:])
+    return x, ws
+
+
+@pytest.mark.parametrize("kind", ["gmm", "dual"])
+@pytest.mark.parametrize(
+    "counts",
+    [[96, 0, 33, 1], [32, 64, 0, 95], [0, 0, 31, 0]],
+    ids=["full-empty-partial", "tile-edges", "one-partial"],
+)
+def test_ragged_dead_tiles_among_live(kind, counts, monkeypatch):
+    """Buckets of three 32-row tiles: tiles past each count are skipped and
+    the partial tile's tail is zeroed, whatever mix of live and dead."""
+    monkeypatch.setattr(ragged, "RAGGED_ROW_TILE", 32)
+    x, ws = _operands(4, 96, 40, 48)
+    assert ragged_blocks(96, 40, 48, 4, len(ws))[0] == 32
+    gs = jnp.asarray(counts, jnp.int32)
+    out = np.asarray(_run(kind, x, ws, gs))
+    np.testing.assert_allclose(
+        out, np.asarray(_oracle(kind, x, ws, gs)), rtol=1e-5, atol=1e-5
+    )
+    for gi, cnt in enumerate(counts):
+        assert (out[gi, cnt:] == 0).all()
+
+
+@pytest.mark.parametrize("kind", ["gmm", "dual"])
+@pytest.mark.parametrize("gpw", [1, 2])
+def test_ragged_all_empty_call_is_zero(kind, gpw):
+    """The no-op prefill lane: every count 0 gives exactly zeros."""
+    x, ws = _operands(4, 64, 32, 48, gpw)
+    out = np.asarray(_run(kind, x, ws, jnp.zeros((4,), jnp.int32), gpw))
+    assert out.shape == (4, 64, 48) and (out == 0).all()
+
+
+@pytest.mark.parametrize("gpw", [2, 4])
+def test_dual_act_ragged_groups_per_weight(gpw):
+    x, ws = _operands(8, 64, 24, 40, gpw)
+    gs = jnp.asarray([0, 64, 5, 33, 0, 0, 17, 40], jnp.int32)
+    np.testing.assert_allclose(
+        np.asarray(_run("dual", x, ws, gs, gpw)),
+        np.asarray(_oracle("dual", x, ws, gs, gpw)),
+        rtol=1e-5, atol=1e-5,
+    )
+
+
+@pytest.mark.parametrize("kind", ["gmm", "dual"])
+@pytest.mark.parametrize("row_blocks", [1, 2], ids=["resident", "row-blocked"])
+def test_ragged_dead_row_nans_never_reach_output(kind, row_blocks, monkeypatch):
+    """NaNs planted past each count stay out of the output, with the
+    group's rows resident in one block and, under a budget too small for
+    that, split over two row blocks (the second dead for short groups)."""
+    g, c, d, f = 4, 64, 32, 48
+    n_w = 1 if kind == "gmm" else 2
+    monkeypatch.setattr(ragged, "RAGGED_ROW_TILE", 32)
+    if row_blocks == 2:
+        monkeypatch.setattr(
+            ragged, "RAGGED_VMEM_BUDGET",
+            ragged.ragged_vmem_bytes(c // 2, d, f, 32, 4, n_w),
+        )
+    assert ragged_blocks(c, d, f, 4, n_w)[1] == c // row_blocks
+    x, ws = _operands(g, c, d, f)
+    counts = jnp.asarray([10, 0, 33, 64], jnp.int32)
+    rows = jnp.arange(c)[None, :, None]
+    x = jnp.where(rows < counts[:, None, None], x, jnp.nan)
+    out = np.asarray(_run(kind, x, ws, counts))
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(
+        out, np.asarray(_oracle(kind, jnp.nan_to_num(x), ws, counts)),
+        rtol=1e-5, atol=1e-5,
+    )
+
+
+def _fetches(seq):
+    """Copies a Pallas pipeline issues for a block sequence: one for the
+    first step, then one per change of block index."""
+    return 1 + sum(a != b for a, b in zip(seq, seq[1:]))
+
+
+@pytest.mark.parametrize(
+    "counts,gpw,nr",
+    [
+        ([5, 0, 0, 9, 0], 1, 1),      # dead groups between and after
+        ([0, 0, 3, 8], 1, 1),         # leading dead groups
+        ([0, 0, 0, 0], 1, 1),         # the no-op lane
+        ([16, 3, 0, 9], 1, 2),        # row blocks past the count
+        ([4, 0, 0, 7, 0, 2], 2, 1),   # groups sharing a weight row
+        ([0, 0, 0, 0, 0, 0], 3, 2),
+    ],
+)
+def test_ragged_fetches_each_live_step_once(counts, gpw, nr):
+    """Walk the grid through the kernels' own index maps: every live step
+    reads its own blocks, each live step's weight panels are copied once,
+    dead steps copy nothing, and an all-empty call copies one block."""
+    nj, bc = 3, 8
+    gs = jnp.asarray(counts, jnp.int32)
+    src, col = (np.asarray(a) for a in ragged_pins(gs, bc * nr, bc, nj))
+    x_map, w_map = ragged_block_maps(nr, gpw)
+    xs, ws, live = [], [], 0
+    for gi in range(len(counts)):
+        for r in range(nr):
+            on = r * bc < counts[gi]
+            live += on
+            for j in range(nj):
+                xi = tuple(int(v) for v in x_map(gi, r, j, gs, src, col))
+                wi = tuple(int(v) for v in w_map(gi, r, j, gs, src, col))
+                if on:
+                    assert xi == (gi, r, 0) and wi == (gi // gpw, 0, j)
+                xs.append(xi)
+                ws.append(wi)
+    assert _fetches(ws) == max(live * nj, 1)
+    assert _fetches(xs) == max(live, 1)
+
+
+@pytest.mark.parametrize("c", [8, 16, 24, 32, 40, 64, 96, 128, 200, 256, 384, 512])
+@pytest.mark.parametrize(
+    "d,f", [(6144, 10752), (6144, 16384)], ids=["dbrx", "mixtral-8x22b"]
+)
+def test_ragged_block_rule_fits_v5e(d, f, c):
+    """At published widths and every bucket height up to 512, both kernels'
+    blocks meet Mosaic's (8, 128) rule, divide their axes, fit the VMEM
+    budget, and keep a 20-slot call to a few hundred grid steps."""
+    for k, n, n_w in ((d, f, 2), (f, d, 1)):
+        bm, bc, bn = ragged_blocks(c, k, n, 2, n_w)
+        assert c % bc == 0 and bc % bm == 0 and n % bn == 0
+        assert bm % 16 == 0 or bm == c    # whole packed bf16 sublane tiles
+        assert bn % 128 == 0 or bn == n
+        assert (
+            ragged.ragged_vmem_bytes(bc, k, bn, bm, 2, n_w)
+            <= ragged.RAGGED_VMEM_BUDGET
+            <= ragged.RAGGED_VMEM_LIMIT
+        )
+        assert 20 * (c // bc) * (n // bn) <= 1000
 
 
 # ---------------------------------------------------------------------------

@@ -69,11 +69,14 @@ def _assert_kernel(hlo: str) -> None:
 G, CAP = 20, 128
 
 
-def test_gmm_ragged_pair_compiles(one_chip):
+# Bucket heights the cells run through the ragged pair: 128 (decode) and
+# 256 (the prefill lane) on the closed-loop cell, 32 and 256 on the open.
+@pytest.mark.parametrize("cap", [32, 128, 256])
+def test_gmm_ragged_pair_compiles(one_chip, cap):
     fn = functools.partial(ops.expert_ffn_ragged, interpret=False)
     hlo = _compile_hlo(
         fn, one_chip,
-        ((G, CAP, D), BF16), ((G, D, F), BF16), ((G, D, F), BF16),
+        ((G, cap, D), BF16), ((G, D, F), BF16), ((G, D, F), BF16),
         ((G, F, D), BF16), ((G,), I32),
     )
     _assert_kernel(hlo)
